@@ -100,7 +100,10 @@ def bench_engine(engine: str, num_clients: int, rounds: int,
     phase_keys = sorted(set().union(*(log.phase_s for log in logs)))
     phase_s = {k: float(np.median([log.phase_s.get(k, 0.0) for log in logs]))
                for k in phase_keys}
+    # the sweep pins its children to the CPU: every row names its
+    # platform, so no CPU row can pass for a chip measurement
     return {"engine": engine, "clients": num_clients,
+            "platform": jax.devices()[0].platform,
             "devices": num_devices, "fraction": fraction,
             "warmup_s": warm_s, "round_s": float(np.median(times)),
             "phase_s": phase_s,

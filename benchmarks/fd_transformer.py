@@ -106,7 +106,10 @@ def bench_shards(model_shards: int, rounds: int, seed: int = 0) -> dict:
     for r in range(1, rounds + 1):
         log = run_round(r, eng, server, method, cfg, x_test, y_test)
         times.append(log.wall_s)
+    # the sweep pins its children to the CPU: every row names its
+    # platform, so no CPU row can pass for a chip measurement
     return {"model_shards": model_shards, "num_devices": num_devices,
+            "platform": jax.devices()[0].platform,
             "mesh": "(1,)" if model_shards == 0 else f"(1, {model_shards})",
             "clients": CLIENTS, "warmup_s": warm_s,
             "round_s": float(np.median(times)),

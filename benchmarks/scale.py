@@ -104,8 +104,11 @@ def run_row(row: dict) -> dict:
         times.append(log.wall_s)
     round_s = float(np.median(times)) if times else warm_log.wall_s
     peak_mb = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 1024.0
+    # the sweep pins its children to the CPU: every row names its
+    # platform, so no CPU row can pass for a chip measurement
     return {
         "name": row["name"], "clients": cfg.num_clients,
+        "platform": jax.devices()[0].platform,
         "wave_size": cfg.wave_size,
         "waves": -(-cfg.num_clients // max(cfg.wave_size, 1)),
         "edges": cfg.num_edge_aggregators,

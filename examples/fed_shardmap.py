@@ -17,7 +17,6 @@ import jax                              # noqa: E402
 import jax.numpy as jnp                 # noqa: E402
 import numpy as np                      # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
 
 from repro.core.aggregation import masked_mean_logits, masked_mean_logits_psum  # noqa: E402
 from repro.core.kmeans import kmeans_fit, min_dist_to_centroids  # noqa: E402
@@ -45,15 +44,15 @@ def client_round(cents, thr, logits_local):
     return teacher[None], valid[None], mask[None]
 
 
-fn = shard_map(client_round, mesh=mesh,
-               in_specs=(P("clients"), P("clients"), P("clients")),
-               out_specs=(P("clients"), P("clients"), P("clients")))
+fn = jax.shard_map(client_round, mesh=mesh,
+                   in_specs=(P("clients"), P("clients"), P("clients")),
+                   out_specs=(P("clients"), P("clients"), P("clients")))
 teacher_sharded, valid, masks = fn(centroids, threshold, logits)
 
 # reference: hub-and-spoke masked mean with the same masks
 ref_teacher, ref_valid = masked_mean_logits(logits, masks)
 
-np.testing.assert_allclose(np.asarray(teacher_sharded[0]),
+np.testing.assert_allclose(np.asarray(teacher_sharded)[0],
                            np.asarray(ref_teacher), rtol=1e-5, atol=1e-6)
 print(f"devices: {jax.device_count()} (one per client)")
 print(f"ID fraction per client: {np.asarray(masks).mean(axis=1).round(2)}")

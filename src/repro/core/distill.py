@@ -38,6 +38,7 @@ def kd_kl_loss(student_logits, teacher_logits, temperature: float = 3.0,
             teacher_logits.reshape(-1, teacher_logits.shape[-1]),
             t, backend="pallas").reshape(lead)
     else:
+        dispatch.count_route("kd_kl", "jnp")
         sp = jax.nn.log_softmax(student_logits.astype(jnp.float32) / t, axis=-1)
         tp = jax.nn.softmax(teacher_logits.astype(jnp.float32) / t, axis=-1)
         tlogp = jax.nn.log_softmax(teacher_logits.astype(jnp.float32) / t, axis=-1)

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from repro.kernels import dispatch
 # canonical impl moved to the dispatch layer; re-exported for importers
 from repro.kernels.dispatch import pairwise_sq_dists as pairwise_sq_dists
+from repro.models.sharding import shard_local
 
 
 class KMeansResult(NamedTuple):
@@ -107,10 +108,15 @@ def _kmeans_fit_pallas(keys, xs, k: int, max_iter: int, tol):
     xs = xs.astype(jnp.float32)
     c = xs.shape[0]
     init = jax.vmap(lambda kk, xx: kmeans_plus_plus(kk, xx, k))(keys, xs)
+    # on a client mesh each device steps the kernel over its own clients
+    lloyd_step = shard_local(
+        kd_ops.lloyd_step, [("clients", None, None)] * 2,
+        [("clients", None), ("clients", None), ("clients", None, None),
+         ("clients", None)])
 
     def step(carry, _):
         cents, done, iters = carry
-        _, _, sums, counts = kd_ops.lloyd_step(xs, cents)
+        _, _, sums, counts = lloyd_step(xs, cents)
         new = jnp.where(counts[..., None] > 0,
                         sums / jnp.maximum(counts[..., None], 1.0), cents)
         shift = jnp.sum(jnp.square(new - cents), axis=(-2, -1))
@@ -122,7 +128,7 @@ def _kmeans_fit_pallas(keys, xs, k: int, max_iter: int, tol):
     (cents, _, iters), _ = jax.lax.scan(
         step, (init, jnp.zeros((c,), bool), jnp.zeros((c,), jnp.int32)),
         None, length=max_iter)
-    assign, min_d2, _, _ = kd_ops.lloyd_step(xs, cents)
+    assign, min_d2, _, _ = lloyd_step(xs, cents)
     inertia = jnp.sum(min_d2, axis=-1)
     return KMeansResult(cents, assign, inertia, iters)
 
@@ -137,7 +143,8 @@ def kmeans_fit(key, x, k: int, max_iter: int = 50, tol: float = 1e-6, *,
     fuses distances + argmin + per-centroid accumulation in one kernel,
     "jnp" is the reference two-matmul body.
     """
-    if dispatch.resolve(backend) == "pallas":
+    if dispatch.count_route("kmeans_fit",
+                            dispatch.resolve(backend)) == "pallas":
         res = _kmeans_fit_pallas(jnp.asarray(key)[None],
                                  jnp.asarray(x)[None], k, max_iter, tol)
         return KMeansResult(*(leaf[0] for leaf in res))
@@ -155,7 +162,8 @@ def kmeans_fit_batched(keys, xs, k: int, max_iter: int = 50, tol: float = 1e-6,
     ``tests/test_dre_contract.py`` checks. On the "pallas" backend the
     client axis is a kernel grid dimension (one trace for any C).
     """
-    if dispatch.resolve(backend) == "pallas":
+    if dispatch.count_route("kmeans_fit",
+                            dispatch.resolve(backend)) == "pallas":
         return _kmeans_fit_pallas(jnp.asarray(keys), jnp.asarray(xs),
                                   k, max_iter, tol)
     return _kmeans_fit_batched_jnp(keys, xs, k, max_iter, tol)

@@ -90,6 +90,9 @@ class RoundLog:
     scrubbed_rows: int = 0
     quarantined: Optional[List[int]] = None
     rollbacks: int = 0
+    # per-client share of the proxy batch its filter kept ID (None = the
+    # engine reported no masks)
+    client_id_fractions: Optional[List[float]] = None
 
 
 @dataclasses.dataclass

@@ -341,6 +341,14 @@ class _Cohort:
         else:
             p_sh = o_sh = None
 
+        # the client vmap maps onto the mesh's client axis, so sharding
+        # constraints and kernel shard_maps inside a phase keep each
+        # client's work on the device that holds it
+        spmd_axis = None if self.mesh is None else self.mesh_axis
+
+        def vmap(fn, in_axes=0):
+            return jax.vmap(fn, in_axes=in_axes, spmd_axis_name=spmd_axis)
+
         def pin_clients(tree):
             return jax.tree.map(lambda leaf: constrain(leaf, "clients"),
                                 tree)
@@ -442,21 +450,21 @@ class _Cohort:
                                       (xb, yb, mb))
             return correct
 
-        self._train = pinned(jax.vmap(train_chunk), state_out=True)
+        self._train = pinned(vmap(train_chunk), state_out=True)
         self._distill = pinned(
-            jax.vmap(distill_chunk, in_axes=(0, 0, None, None, 0, 0, 0)),
+            vmap(distill_chunk, in_axes=(0, 0, None, None, 0, 0, 0)),
             state_out=True)
         self._distill_private = pinned(
-            jax.vmap(distill_private_chunk,
+            vmap(distill_private_chunk,
                      in_axes=(0, 0, 0, 0, None, None, 0, 0, 0)),
             state_out=True)
         self._predict = pinned(
-            jax.vmap(lambda p, xb: apply_fn(p, xb, False), in_axes=(0, None)))
+            vmap(lambda p, xb: apply_fn(p, xb, False), in_axes=(0, None)))
         self._eval = pinned(
-            jax.vmap(eval_chunk, in_axes=(0, None, None, None)))
-        self._classwise = pinned(jax.vmap(classwise_chunk))
+            vmap(eval_chunk, in_axes=(0, None, None, None)))
+        self._classwise = pinned(vmap(classwise_chunk))
         self._kmeans_masks = pinned(
-            jax.vmap(kmeans_mask_chunk, in_axes=(0, 0, 0, None, None)))
+            vmap(kmeans_mask_chunk, in_axes=(0, 0, 0, None, None)))
 
         def kulsif_mask_chunk(alpha, aux, priv, n, thr, cid, sigma, lam,
                               pxf, owner):
@@ -468,7 +476,7 @@ class _Cohort:
             return (owner == cid) | (r >= thr)
 
         self._kulsif_masks = pinned(
-            jax.vmap(kulsif_mask_chunk,
+            vmap(kulsif_mask_chunk,
                      in_axes=(0, 0, 0, 0, 0, 0, None, None, None, None)))
 
     # -------------------------------------------------------------- DRE learn

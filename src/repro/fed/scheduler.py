@@ -193,7 +193,7 @@ class _RoundState:
 
     __slots__ = ("r", "part", "kw", "idx", "px", "powner", "means_counts",
                  "teacher", "valid", "teacher_by_class", "valid_by_class",
-                 "local_losses", "distill_losses", "id_frac",
+                 "local_losses", "distill_losses", "id_frac", "id_fracs",
                  "mean_staleness", "accs", "phase_s", "sim_finish_s",
                  "report_payload", "rpart", "sampled", "reports_pending",
                  "report_logits", "report_masks", "report_arrival",
@@ -215,6 +215,7 @@ class _RoundState:
         self.local_losses: List[float] = []
         self.distill_losses: List[float] = []
         self.id_frac = 1.0
+        self.id_fracs = None        # per client (0.0 where it sent nothing)
         self.mean_staleness = 0.0
         self.accs = None
         self.phase_s: Dict[str, float] = {}
@@ -268,6 +269,7 @@ class _RoundState:
             "local_losses": [float(v) for v in self.local_losses],
             "distill_losses": [float(v) for v in self.distill_losses],
             "id_frac": float(self.id_frac),
+            "id_fracs": self.id_fracs,
             "mean_staleness": float(self.mean_staleness),
             "accs": (None if self.accs is None
                      else [float(a) for a in self.accs]),
@@ -304,6 +306,7 @@ class _RoundState:
         self.local_losses = [float(v) for v in sd["local_losses"]]
         self.distill_losses = [float(v) for v in sd["distill_losses"]]
         self.id_frac = float(sd["id_frac"])
+        self.id_fracs = sd["id_fracs"]
         self.mean_staleness = float(sd["mean_staleness"])
         accs = sd["accs"]
         self.accs = None if accs is None else [float(a) for a in accs]
@@ -1093,6 +1096,7 @@ class RoundScheduler:
         st.id_frac = (float(masks.mean()) if part is None
                       else (float(masks[part].mean())
                             if part.any() else 0.0))
+        st.id_fracs = [float(v) for v in masks.mean(axis=1)]
         self.server.ingest_reports(st.r, part, st.idx, logits, masks,
                                    decay=cfg.staleness_decay,
                                    entropy_filter=self.method.server_filter)
@@ -1185,6 +1189,7 @@ class RoundScheduler:
             distill_loss=(float(np.mean(st.distill_losses))
                           if st.distill_losses else 0.0),
             id_fraction=st.id_frac,
+            client_id_fractions=st.id_fracs,
             bytes_up=self.server.bytes_received,
             bytes_down=self.server.bytes_broadcast,
             wall_s=sum(st.phase_s.values()),

@@ -41,6 +41,7 @@ change: the default-backend bit-for-bit guarantee rides on them.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import os
 from typing import List, Optional
@@ -48,10 +49,22 @@ from typing import List, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.models.sharding import shard_local
+
 BACKENDS = ("auto", "pallas", "jnp")
 ENV_VAR = "REPRO_KERNEL_BACKEND"
 
 _context_stack: List[str] = []
+
+# (op, route) -> how often an op was dispatched that way; inside jit that
+# is once per trace. ``chip_smoke.py`` prints it, so a reference route
+# taken on the chip is seen rather than assumed away.
+route_counts: collections.Counter = collections.Counter()
+
+
+def count_route(op: str, route: str) -> str:
+    route_counts[(op, route)] += 1
+    return route
 
 
 def _validate(name: str, source: str) -> str:
@@ -109,10 +122,13 @@ def kernel_backend(name: str):
 # ---------------------------------------------------------------------------
 
 def pairwise_sq_dists(x, c):
-    """‖x−c‖² via the matmul form (MXU-friendly): x:(n,d), c:(k,d) -> (n,k)."""
+    """‖x−c‖² via the matmul form (MXU-friendly): x:(n,d), c:(k,d) -> (n,k).
+
+    The cross term is a full-f32 matmul: the form cancels badly at wide
+    inputs, and a TPU runs a default-precision f32 matmul in bf16 passes."""
     x2 = jnp.sum(jnp.square(x), axis=-1, keepdims=True)        # (n,1)
     c2 = jnp.sum(jnp.square(c), axis=-1)                       # (k,)
-    cross = x @ c.T                                            # (n,k)
+    cross = jnp.matmul(x, c.T, precision=jax.lax.Precision.HIGHEST)  # (n,k)
     return jnp.maximum(x2 - 2.0 * cross + c2[None, :], 0.0)
 
 
@@ -153,7 +169,7 @@ def lloyd_step(x, centroids, *, backend: Optional[str] = None):
     (n, k) one-hot never reaches HBM and there is no second full matmul
     pass over the data.
     """
-    if resolve(backend) == "pallas":
+    if count_route("lloyd_step", resolve(backend)) == "pallas":
         from repro.kernels.kmeans_dist import ops as kd_ops
         return kd_ops.lloyd_step(x, centroids)
     if x.ndim == 3:
@@ -171,10 +187,13 @@ def kd_kl_per_sample(student_logits, teacher_logits, temperature: float,
     ``temperature`` is compile-time static on the Pallas path — gradients
     w.r.t. it are not defined there (they never are in the FD protocol).
     """
-    if resolve(backend) == "pallas":
+    if count_route("kd_kl", resolve(backend)) == "pallas":
         from repro.kernels.distill_kl import ops as kl_ops
-        return kl_ops.kd_kl_per_sample_vjp(student_logits, teacher_logits,
-                                           float(temperature))
+        temp = float(temperature)
+        return shard_local(
+            lambda s, t: kl_ops.kd_kl_per_sample_vjp(s, t, temp),
+            [("batch", None)] * 2, ("batch",))(student_logits,
+                                               teacher_logits)
     from repro.kernels.distill_kl import ref as kl_ref
     return kl_ref.kd_kl_per_sample(student_logits, teacher_logits,
                                    temperature)
@@ -186,9 +205,10 @@ def rbf_matrix(a, b, sigma, *, backend: Optional[str] = None):
     The KuLSIF-DRE learn/estimate hot-spot; the Pallas path tiles the
     gram matrix through VMEM (peak memory one tile, not n×m).
     """
-    if resolve(backend) == "pallas":
+    if count_route("rbf_matrix", resolve(backend)) == "pallas":
         from repro.kernels.kulsif_rbf import ops as rbf_ops
-        return rbf_ops.rbf_matrix(a, b, sigma)
+        return shard_local(rbf_ops.rbf_matrix, [(None, None)] * 2 + [()],
+                           (None, None))(a, b, jnp.float32(sigma))
     return _rbf_matrix_jnp(a, b, sigma)
 
 
@@ -205,11 +225,17 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     reference path regardless of backend. Differentiable on both routes
     (the kernel carries a ``custom_vjp``; see ``flash_attention.ops``).
     """
-    if window == 0 and resolve(backend) == "pallas":
+    route = resolve(backend) if window == 0 else "jnp (window)"
+    if count_route("flash_attention", route) == "pallas":
         from repro.kernels.flash_attention import ops as fa_ops
-        o = fa_ops.attention(q.swapaxes(1, 2), k.swapaxes(1, 2),
-                             v.swapaxes(1, 2), causal=causal)
-        return o.swapaxes(1, 2).astype(v.dtype)
+
+        def attend(q, k, v):
+            return fa_ops.attention(q.swapaxes(1, 2), k.swapaxes(1, 2),
+                                    v.swapaxes(1, 2),
+                                    causal=causal).swapaxes(1, 2)
+
+        axes = ("batch", "seq", "heads", "head_dim")
+        return shard_local(attend, [axes] * 3, axes)(q, k, v).astype(v.dtype)
     from repro.models import layers as L
     mask = L.make_mask(q.shape[1], k.shape[1], causal=causal, window=window)
     return L.attention_scores(q, k, v, mask)

@@ -43,14 +43,18 @@ def _kernel(s_ref, t_ref, temp_ref, out_ref):
     t_lse = jnp.log(jnp.sum(jnp.exp(t - t_max), axis=-1, keepdims=True)) + t_max
     s_logp = s - s_lse
     t_logp = t - t_lse
-    kl = jnp.sum(jnp.exp(t_logp) * (t_logp - s_logp), axis=-1)
+    kl = jnp.sum(jnp.exp(t_logp) * (t_logp - s_logp), axis=-1, keepdims=True)
     out_ref[...] = kl * temp * temp
 
 
 def kd_kl_pallas(student, teacher, temperature, *, block_n: int = BLOCK_N,
                  interpret: bool = True):
     """student/teacher: (n, K), n a multiple of block_n (ops pads).
-    Returns per-sample KL (n,) f32."""
+    Returns per-sample KL (n,) f32.
+
+    Per-sample values travel as an (n, 1) column with (block_n, 1) blocks:
+    a 1-D (block_n,) block does not match XLA's 1-D tiling once n spans
+    more than one block, and Mosaic refuses it."""
     n, k = student.shape
     temp = jnp.asarray([temperature], jnp.float32)
     grid = (n // block_n,)
@@ -62,10 +66,10 @@ def kd_kl_pallas(student, teacher, temperature, *, block_n: int = BLOCK_N,
             pl.BlockSpec((block_n, k), lambda i: (i, 0)),
             pl.BlockSpec((1,), lambda i: (0,)),
         ],
-        out_specs=pl.BlockSpec((block_n,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
+        out_specs=pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
         interpret=interpret,
-    )(student, teacher, temp)
+    )(student, teacher, temp)[:, 0]
 
 
 def _log_softmaxes(s_ref, t_ref, temp: float):
@@ -81,7 +85,7 @@ def _log_softmaxes(s_ref, t_ref, temp: float):
 
 def _bwd_ds_kernel(s_ref, t_ref, g_ref, ds_ref, *, temp: float):
     s_logp, t_logp = _log_softmaxes(s_ref, t_ref, temp)
-    gt = g_ref[...].astype(jnp.float32)[:, None] * temp
+    gt = g_ref[...].astype(jnp.float32) * temp            # (bn, 1)
     ds_ref[...] = (gt * (jnp.exp(s_logp) - jnp.exp(t_logp))
                    ).astype(ds_ref.dtype)
 
@@ -91,7 +95,7 @@ def _bwd_dt_kernel(s_ref, t_ref, g_ref, dt_ref, *, temp: float):
     tp = jnp.exp(t_logp)
     # f = KL_i / T² — recomputed, not saved (one extra reduction in VMEM)
     f = jnp.sum(tp * (t_logp - s_logp), axis=-1, keepdims=True)
-    gt = g_ref[...].astype(jnp.float32)[:, None] * temp
+    gt = g_ref[...].astype(jnp.float32) * temp            # (bn, 1)
     dt_ref[...] = (gt * tp * ((t_logp - s_logp) - f)).astype(dt_ref.dtype)
 
 
@@ -103,12 +107,12 @@ def _bwd_call(kern, out_dtype, student, teacher, g, block_n, interpret):
         in_specs=[
             pl.BlockSpec((block_n, k), lambda i: (i, 0)),
             pl.BlockSpec((block_n, k), lambda i: (i, 0)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
+            pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_n, k), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, k), out_dtype),
         interpret=interpret,
-    )(student, teacher, g)
+    )(student, teacher, g[:, None])
 
 
 def kd_kl_bwd_pallas(student, teacher, g, temperature: float, *,

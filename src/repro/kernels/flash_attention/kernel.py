@@ -52,16 +52,18 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         valid = valid & (kpos <= qpos)
     s = jnp.where(valid, s, NEG_INF)
 
-    m_prev = m_scr[...]                                   # (bq,)
+    # running max/denominator are (bq, 1) columns: Mosaic has no layout
+    # for a 1-D scratch, nor for reshaping a bool vector into a column
+    m_prev = m_scr[...]                                   # (bq, 1)
     l_prev = l_scr[...]
-    m_cur = jnp.max(s, axis=-1)
+    m_cur = jnp.max(s, axis=-1, keepdims=True)
     m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(s - m_new[:, None])
-    # rows with every key masked: exp(NEG_INF - NEG_INF) would be 1; zero them
-    p = jnp.where((m_new == NEG_INF)[:, None], 0.0, p)
+    # masked keys carry no mass — also in rows with every key masked so
+    # far, where exp(NEG_INF - NEG_INF) would be 1
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
     alpha = jnp.where(m_prev == NEG_INF, 0.0, jnp.exp(m_prev - m_new))
-    l_new = alpha * l_prev + jnp.sum(p, axis=-1)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
+    l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     m_scr[...] = m_new
     l_scr[...] = l_new
@@ -69,7 +71,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(ki == num_kv_blocks - 1)
     def _finalize():
         lsum = l_scr[...]
-        o_ref[0, 0] = (acc_scr[...] / jnp.maximum(lsum, 1e-30)[:, None]
+        o_ref[0, 0] = (acc_scr[...] / jnp.maximum(lsum, 1e-30)
                        ).astype(o_ref.dtype)
 
 
@@ -97,8 +99,8 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, kv_len: int = 0,
         out_specs=pl.BlockSpec((1, 1, block_q, h), lambda b, n, qi, ki: (b, n, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, n, sq, h), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),      # running max m
-            pltpu.VMEM((block_q,), jnp.float32),      # running denom l
+            pltpu.VMEM((block_q, 1), jnp.float32),    # running max m
+            pltpu.VMEM((block_q, 1), jnp.float32),    # running denom l
             pltpu.VMEM((block_q, h), jnp.float32),    # output accumulator
         ],
         interpret=interpret,

@@ -30,6 +30,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 BLOCK_T = 256
+# f32 on the MXU: the matmul-form distance ‖x‖² − 2x·c + ‖c‖² cancels badly
+# at 3072-wide inputs unless the cross term keeps full f32 precision
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _kernel(x_ref, c_ref, thr_ref, dist_ref, mask_ref):
@@ -38,7 +41,8 @@ def _kernel(x_ref, c_ref, thr_ref, dist_ref, mask_ref):
     x2 = jnp.sum(x * x, axis=-1, keepdims=True)  # (bt, 1)
     c2 = jnp.sum(c * c, axis=-1)                 # (C,)
     cross = jax.lax.dot_general(                 # (bt, C) — the MXU matmul
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        x, c, (((1,), (1,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32)
     d2 = jnp.maximum(x2 - 2.0 * cross + c2[None, :], 0.0)
     md = jnp.sqrt(jnp.min(d2, axis=-1))
     dist_ref[...] = md
@@ -83,11 +87,13 @@ def _lloyd_kernel(x_ref, c_ref, assign_ref, mind2_ref, sums_ref, counts_ref,
     x2 = jnp.sum(x * x, axis=-1, keepdims=True)  # (bt, 1)
     c2 = jnp.sum(c * c, axis=-1)                 # (k,)
     cross = jax.lax.dot_general(                 # (bt, k) — the MXU matmul
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        x, c, (((1,), (1,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32)
     d2 = jnp.maximum(x2 - 2.0 * cross + c2[None, :], 0.0)
     assign = jnp.argmin(d2, axis=-1)             # (bt,)
-    assign_ref[0] = assign.astype(jnp.int32)
-    mind2_ref[0] = jnp.min(d2, axis=-1)
+    # per-sample outputs are lane-dense (1, bt) rows of a (C, 1, t) array
+    assign_ref[0] = assign.astype(jnp.int32)[None, :]
+    mind2_ref[0] = jnp.min(d2, axis=-1)[None, :]
     # (bt, k) one-hot lives only in this VMEM tile; rows past the true
     # sample count (ops.py pads t up to a block multiple) carry no mass
     row = j * block_t + jax.lax.broadcasted_iota(jnp.int32, (block_t, 1), 0)
@@ -96,15 +102,16 @@ def _lloyd_kernel(x_ref, c_ref, assign_ref, mind2_ref, sums_ref, counts_ref,
           == jax.lax.broadcasted_iota(jnp.int32, (block_t, k), 1)
           ).astype(jnp.float32) * valid
     part_sums = jax.lax.dot_general(             # (k, d) — second MXU matmul
-        oh, x, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    part_counts = jnp.sum(oh, axis=0)            # (k,)
+        oh, x, (((0,), (0,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32)
+    part_counts = jnp.sum(oh, axis=0, keepdims=True)  # (1, k)
 
     @pl.when(j == 0)
     def _init():
         sums_ref[...] = jnp.zeros_like(sums_ref)
         counts_ref[...] = jnp.zeros_like(counts_ref)
 
-    # the (k, d)/(k,) output blocks have a constant index_map along the
+    # the (k, d)/(1, k) output blocks have a constant index_map along the
     # tile axis, so they stay resident and accumulate across grid steps
     sums_ref[0] += part_sums
     counts_ref[0] += part_counts
@@ -121,7 +128,7 @@ def lloyd_step_pallas(x, centroids, *, block_t: int = BLOCK_T,
     grid = (bc, t // block_t)
     kern = functools.partial(_lloyd_kernel, block_t=block_t,
                              n_true=n_true if n_true is not None else t)
-    return pl.pallas_call(
+    assign, min_d2, sums, counts = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
@@ -129,16 +136,17 @@ def lloyd_step_pallas(x, centroids, *, block_t: int = BLOCK_T,
             pl.BlockSpec((1, k, d), lambda c, j: (c, 0, 0)),   # resident
         ],
         out_specs=[
-            pl.BlockSpec((1, block_t), lambda c, j: (c, j)),
-            pl.BlockSpec((1, block_t), lambda c, j: (c, j)),
+            pl.BlockSpec((1, 1, block_t), lambda c, j: (c, 0, j)),
+            pl.BlockSpec((1, 1, block_t), lambda c, j: (c, 0, j)),
             pl.BlockSpec((1, k, d), lambda c, j: (c, 0, 0)),   # accumulated
-            pl.BlockSpec((1, k), lambda c, j: (c, 0)),         # accumulated
+            pl.BlockSpec((1, 1, k), lambda c, j: (c, 0, 0)),   # accumulated
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bc, t), jnp.int32),
-            jax.ShapeDtypeStruct((bc, t), jnp.float32),
+            jax.ShapeDtypeStruct((bc, 1, t), jnp.int32),
+            jax.ShapeDtypeStruct((bc, 1, t), jnp.float32),
             jax.ShapeDtypeStruct((bc, k, d), jnp.float32),
-            jax.ShapeDtypeStruct((bc, k), jnp.float32),
+            jax.ShapeDtypeStruct((bc, 1, k), jnp.float32),
         ],
         interpret=interpret,
     )(x, centroids)
+    return assign[:, 0], min_d2[:, 0], sums, counts[:, 0]

@@ -36,18 +36,23 @@ def _run_lloyd(x, centroids, block_t, interpret):
     return assign[:, :n], min_d2[:, :n], sums, counts
 
 
-def lloyd_step(x, centroids, *, block_t: int = BLOCK_T,
+def lloyd_step(x, centroids, *, block_t: int | None = None,
                interpret: bool | None = None):
     """Public op: one fused Lloyd iteration of the KMeans-DRE fit.
 
     ``x``: (n, d) or (C, n, d); ``centroids``: (k, d) / (C, k, d).
     Returns (assign i32, min_d2 f32, sums (…, k, d) f32, counts (…, k)
     f32) with matching leading axes; padded rows never reach sums/counts.
+    ``block_t`` None halves the row tile for rows wider than 2048: for a
+    v5e, the (C=10, t=4000, d=3072, k=10) step runs out of VMEM with
+    256-row tiles and fits with 128 (``tests/test_tpu_compile.py``).
     """
     if interpret is None:
         interpret = default_interpret()
     x = jnp.asarray(x)
     centroids = jnp.asarray(centroids)
+    if block_t is None:
+        block_t = BLOCK_T if x.shape[-1] <= 2048 else BLOCK_T // 2
     if x.ndim == 2:
         out = _run_lloyd(x[None], centroids[None], block_t, interpret)
         return tuple(o[0] for o in out)

@@ -22,8 +22,9 @@ def _kernel(a_ref, b_ref, sig_ref, out_ref):
     b = b_ref[...].astype(jnp.float32)            # (bn, d)
     a2 = jnp.sum(a * a, axis=-1, keepdims=True)   # (bm, 1)
     b2 = jnp.sum(b * b, axis=-1)                  # (bn,)
-    cross = jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    cross = jax.lax.dot_general(                  # full f32 on the MXU
+        a, b, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
     d2 = jnp.maximum(a2 - 2.0 * cross + b2[None, :], 0.0)
     sig = sig_ref[0]
     out_ref[...] = jnp.exp(-d2 / (2.0 * sig * sig))

@@ -42,6 +42,7 @@ from typing import List, Optional, Tuple
 import jax
 
 from repro.checkpoint import latest_step, restore_state, save_state
+from repro.common.compile_cache import enable_compile_cache
 from repro.core.methods import get_method
 from repro.core.protocol import RoundLog
 from repro.fed import participation, scheduler as sched_mod, simulator
@@ -149,6 +150,7 @@ def main(argv=None):
     ap.add_argument("--json", default="",
                     help="write the full round-log history here on exit")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     cfg = config_from_args(args)
     crash_at = parse_crash_spec(args.crash_after_phase)
     ckpt_on = bool(args.ckpt_dir) and args.ckpt_every > 0

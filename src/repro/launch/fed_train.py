@@ -12,6 +12,7 @@ from __future__ import annotations
 import argparse
 import json
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.types import FedConfig
 from repro.core.methods import METHODS
 from repro.fed import simulator
@@ -312,15 +313,21 @@ def print_round(log, num_clients: int) -> None:
           f"up={log.bytes_up/1e6:.1f}MB{extra}")
 
 
-def main(argv=None):
+def main(argv=None, on_round=None):
+    """Run one experiment; ``on_round(log)`` is called after each retired
+    round is printed (in-process callers, e.g. ``chip_smoke.py``, time
+    rounds with it)."""
     ap = argparse.ArgumentParser()
     add_config_args(ap)
     ap.add_argument("--json", default="")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     cfg = config_from_args(args)
 
     def progress(log):
         print_round(log, args.clients)
+        if on_round is not None:
+            on_round(log)
 
     res = simulator.run(cfg, args.dataset, n_train=args.n_train,
                         n_test=args.n_test, progress=progress)

@@ -102,6 +102,33 @@ def constrain(x, *axes: Optional[str]):
         x, jax.sharding.NamedSharding(getattr(_state, "mesh"), spec))
 
 
+def shard_local(fn, in_axes, out_axes):
+    """``fn`` run on each device's local blocks of the installed mesh.
+
+    A Pallas TPU kernel has no partitioning rule, so under a mesh XLA
+    refuses to split it; its call sites wrap it here. ``in_axes`` gives one
+    tuple of logical axis names per argument (one name or None per dim,
+    resolved like ``constrain``); ``out_axes`` is one such tuple, or a list
+    of them for a tuple of results. Inside a ``vmap`` over a mesh-sharded
+    axis, pass the vmap ``spmd_axis_name`` so that its batch dim joins the
+    specs. Without an installed mesh, ``fn`` itself is returned.
+    """
+    rules = getattr(_state, "rules", None)
+    mesh = getattr(_state, "mesh", None)
+    if rules is None or mesh is None:
+        return fn
+    names = set(mesh.axis_names)
+
+    def spec(axes):
+        return P(*[_resolve(a, rules, names) for a in axes])
+
+    out_specs = (tuple(spec(a) for a in out_axes)
+                 if isinstance(out_axes, list) else spec(out_axes))
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=tuple(spec(a) for a in in_axes),
+                         out_specs=out_specs, check_vma=False)
+
+
 def gather_fsdp(params_subtree):
     """Explicit ZeRO-3 weight gathering (EXPERIMENTS.md §Perf pair A).
 

@@ -85,6 +85,10 @@ def main(argv=None) -> None:
     ap.add_argument("--byzantine-frac", type=float, default=0.0)
     ap.add_argument("--fault-prob", type=float, default=0.0)
     ap.add_argument("--robust-aggregation", default="mean")
+    ap.add_argument("--kernel-backend", default="auto",
+                    help="pallas: every engine runs the kernels (interpret "
+                         "mode off-TPU), so the mesh entry runs them under "
+                         "shard_map")
     args = ap.parse_args(argv)
 
     # must happen before the first jax import (device count is init-time)
@@ -108,12 +112,14 @@ def main(argv=None) -> None:
                      fault_mode=args.fault_mode,
                      byzantine_frac=args.byzantine_frac,
                      fault_prob=args.fault_prob,
-                     robust_aggregation=args.robust_aggregation)
+                     robust_aggregation=args.robust_aggregation,
+                     kernel_backend=args.kernel_backend)
         print(f"PARITY-OK clients={c} devices={args.devices} "
               f"model_shards={args.model_shards} dataset={args.dataset} "
               f"participation={args.participation} "
               f"round_mode={args.round_mode} "
-              f"fault_mode={args.fault_mode}")
+              f"fault_mode={args.fault_mode} "
+              f"kernel_backend={args.kernel_backend}")
 
 
 if __name__ == "__main__":

@@ -122,6 +122,29 @@ def test_mesh_sharded_parity_forced_devices():
     assert res.stdout.count("PARITY-OK") == 2, res.stdout
 
 
+def test_mesh_sharded_parity_pallas_kernels():
+    """The same parity with every engine on the Pallas kernels: on the mesh
+    each kernel runs under ``shard_map`` over the client axis (a
+    ``pallas_call`` has no partitioning rule), C=5 on 4 devices pads the
+    tail with validity-gated dummy clients."""
+    if jax.device_count() >= 4:
+        import _mesh_parity_prog
+        _mesh_parity_prog.check_parity(5, 4, kernel_backend="pallas")
+        return
+    here = os.path.dirname(os.path.abspath(__file__))
+    env = dict(os.environ)
+    env["PYTHONPATH"] = (os.path.abspath(os.path.join(here, "..", "src"))
+                         + os.pathsep + env.get("PYTHONPATH", ""))
+    env.setdefault("JAX_PLATFORMS", "cpu")
+    res = subprocess.run(
+        [sys.executable, os.path.join(here, "_mesh_parity_prog.py"),
+         "--devices", "4", "--clients", "5", "--kernel-backend", "pallas"],
+        env=env, capture_output=True, text=True, timeout=480)
+    assert res.returncode == 0, (
+        f"mesh parity subprocess failed:\n{res.stdout}\n{res.stderr}")
+    assert "PARITY-OK" in res.stdout, res.stdout
+
+
 def test_run_round_honors_cfg_engine(monkeypatch):
     """Regression: run_round built its engine with as_engine(clients) —
     dropping cfg.engine — so a raw client list under engine='cohort'

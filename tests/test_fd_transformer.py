@@ -91,6 +91,27 @@ def test_transformer_2d_mesh_parity():
     assert "PARITY-OK" in res.stdout, res.stdout
 
 
+def test_transformer_2d_mesh_parity_pallas_kernels():
+    """The 2-D parity with the flash-attention and distill-KL kernels on
+    every engine: on the (clients, model) mesh they run under
+    ``shard_map``, attention split over heads on the model axis."""
+    if jax.device_count() >= 4:
+        _mesh_parity_prog.check_parity(4, 4, model_shards=2,
+                                       dataset="lm_tokens",
+                                       n_train=300, n_test=150,
+                                       kernel_backend="pallas")
+        return
+    here, env = _subprocess_env()
+    res = subprocess.run(
+        [sys.executable, os.path.join(here, "_mesh_parity_prog.py"),
+         "--devices", "4", "--clients", "4", "--model-shards", "2",
+         "--dataset", "lm_tokens", "--kernel-backend", "pallas"],
+        env=env, capture_output=True, text=True, timeout=480)
+    assert res.returncode == 0, (
+        f"2-D mesh parity subprocess failed:\n{res.stdout}\n{res.stderr}")
+    assert "PARITY-OK" in res.stdout, res.stdout
+
+
 def test_model_shards_env_is_inert_without_mesh(monkeypatch):
     """$REPRO_MODEL_SHARDS (the CI matrix vehicle) must never change a
     meshless run: engine selection ignores it when num_devices == 0, so

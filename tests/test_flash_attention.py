@@ -140,6 +140,20 @@ def test_dispatch_window_always_takes_reference_path():
         np.testing.assert_array_equal(np.asarray(got), want)
 
 
+def test_dispatch_counts_the_route_taken():
+    """``route_counts`` names the reference route a windowed call takes
+    under the Pallas backend, so a chip run shows it."""
+    q, k, v = _layers_qkv(jax.random.PRNGKey(6))
+    dispatch.route_counts.clear()
+    dispatch.flash_attention(q, k, v, causal=True, backend="pallas")
+    dispatch.flash_attention(q, k, v, causal=True, window=8,
+                             backend="pallas")
+    dispatch.flash_attention(q, k, v, causal=True, backend="jnp")
+    assert dispatch.route_counts == {("flash_attention", "pallas"): 1,
+                                     ("flash_attention", "jnp (window)"): 1,
+                                     ("flash_attention", "jnp"): 1}
+
+
 def test_explicit_backend_beats_context():
     q, k, v = _layers_qkv(jax.random.PRNGKey(7))
     from repro.models import layers as L

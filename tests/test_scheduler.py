@@ -211,6 +211,18 @@ def test_phase_wall_clock_breakdown_recorded():
     assert finishes == sorted(finishes) and finishes[0] > 0.0
 
 
+def test_per_client_id_fractions_recorded():
+    """Each round log carries every client's filter keep-rate; at full
+    participation their mean is the round's ID fraction."""
+    cfg = _cfg(rounds=2, round_mode="sync", engine="cohort")
+    res = simulator.run(cfg, "mnist_feat", n_train=800, n_test=300)
+    for log in res.rounds:
+        fracs = log.client_id_fractions
+        assert len(fracs) == cfg.num_clients
+        assert all(0.0 <= v <= 1.0 for v in fracs)
+        assert np.mean(fracs) == pytest.approx(log.id_fraction, abs=1e-6)
+
+
 def test_client_speeds_deterministic_and_bounded():
     a = client_speeds(8, seed=3, straggler_factor=4.0)
     b = client_speeds(8, seed=3, straggler_factor=4.0)

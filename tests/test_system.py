@@ -79,3 +79,18 @@ def test_comm_accounting_monotone(strong_results):
     # filtered upload must be smaller than unfiltered (same rounds/batch)
     assert strong_results["edgefd"].rounds[-1].bytes_up < \
         strong_results["fedmd"].rounds[-1].bytes_up
+
+
+def test_fed_train_entry_point_reports_each_round(capsys):
+    """``fed_train.main`` (the entry point ``chip_smoke.py`` drives in
+    process) hands every retired round to ``on_round`` after printing it."""
+    from repro.launch import fed_train
+    seen = []
+    res = fed_train.main(["--method", "edgefd", "--scenario", "strong",
+                          "--dataset", "mnist_feat", "--engine", "cohort",
+                          "--clients", "4", "--rounds", "2",
+                          "--n-train", "800", "--n-test", "200"],
+                         on_round=seen.append)
+    assert [log.round for log in seen] == [0, 1]
+    assert seen == list(res.rounds)
+    assert capsys.readouterr().out.count("round ") >= 2
