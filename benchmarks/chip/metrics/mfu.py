@@ -1,0 +1,10 @@
+"""Model FLOP utilization of the whole round, in percent: the model FLOPs
+one round of the protocol requires (counted from the configuration's
+declared layers, ``fdbench.flops.round_flops``) over the measured
+``round_s`` times the chip's peak bf16 FLOP/s (``peaks.json``)."""
+
+
+def read(ctx):
+    if ctx.peaks is None or not ctx.round_s:
+        return None
+    return 100.0 * ctx.round_flops / (ctx.round_s * ctx.peaks["bf16_flops"])

@@ -1,0 +1,10 @@
+"""Host seconds per round in the scheduler's ``distill`` phase node: client
+distillation through the distill-KL kernel. Read from
+``RoundLog.phase_s`` and averaged over the measured window's rounds."""
+
+PHASE = "distill"
+
+
+def read(ctx):
+    vals = [r["phase_s"][PHASE] for r in ctx.rounds if PHASE in r["phase_s"]]
+    return sum(vals) / len(vals) if vals else None
