@@ -1,0 +1,10 @@
+"""Device->host reads of the server per round: the ``server.fetch`` spans
+in the traced window over its rounds. The program opens one such span for
+each read it books as ``RoundLog.counters["server.syncs"]``. Returns
+nothing where the program opens no such spans."""
+from fdbench import spans
+
+
+def read(ctx):
+    got = spans.of(ctx)
+    return None if got is None else got.per_round_count("server.fetch")

@@ -36,7 +36,7 @@ SAMPLES_PER_CLIENT = 64
 MLP_HIDDEN = (64,)
 # host-measured fields can never match across runs; everything else must
 OVERHEAD_FRAC_MAX = 0.5  # ckpt time vs round compute, quick-scale gate
-MEASURED_FIELDS = ("wall_s", "phase_s")
+MEASURED_FIELDS = ("wall_s", "phase_s", "counters.compiles")
 
 FIXED_COSTS = {"local_train": 1.0, "report": 0.1, "aggregate": 0.3,
                "distill": 1.0, "eval": 0.0}
@@ -58,8 +58,16 @@ def _build(cfg):
 
 
 def _strip(logs):
-    return [{k: v for k, v in dataclasses.asdict(lg).items()
-             if k not in MEASURED_FIELDS} for lg in logs]
+    out = []
+    for lg in logs:
+        d = {k: v for k, v in dataclasses.asdict(lg).items()
+             if k not in MEASURED_FIELDS}
+        for name in MEASURED_FIELDS:   # "a.b": key b of dict field a
+            field, _, key = name.partition(".")
+            if key and field in d:
+                d[field] = {k: v for k, v in d[field].items() if k != key}
+        out.append(d)
+    return out
 
 
 def bench(*, clients: int, rounds: int, engine: str = "loop",

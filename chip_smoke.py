@@ -81,23 +81,6 @@ def peak_bytes(device) -> str:
     return "not reported" if peak is None else f"{peak} B"
 
 
-class CompileClock:
-    """Sums XLA backend-compile time as JAX reports it."""
-
-    EVENT = "/jax/core/compile/backend_compile_duration"
-
-    def __init__(self):
-        import jax
-        self.seconds = 0.0
-        self.count = 0
-        jax.monitoring.register_event_duration_secs_listener(self._listen)
-
-    def _listen(self, event, duration, **_):
-        if event == self.EVENT:
-            self.seconds += duration
-            self.count += 1
-
-
 def check_kernels(key_seed: int) -> None:
     """Each wired kernel, compiled by Mosaic, against a float64 NumPy
     reference on a small input.
@@ -208,18 +191,19 @@ def check_kernels(key_seed: int) -> None:
           np.einsum("bnqk,bnkh->bnqh", probs, v), 5e-2)
 
 
-def run_phase(name: str, argv, device, clock, *, min_acc=None):
+def run_phase(name: str, argv, device, *, min_acc=None):
     """One in-process ``fed_train.main`` run; prints its observations and
     checks its round logs. Returns the logs."""
     import jax
     import numpy as np
 
+    from repro.common import tracing
     from repro.kernels import dispatch
     from repro.launch import fed_train
 
     print(f"\n== phase {name}: fed_train {' '.join(argv)}", flush=True)
     dispatch.route_counts.clear()
-    compile_s0, compiles0 = clock.seconds, clock.count
+    compiles0, compile_s0 = tracing.compiles()
     stamps = []
 
     def on_round(_log):
@@ -237,8 +221,9 @@ def run_phase(name: str, argv, device, clock, *, min_acc=None):
                        in sorted(dispatch.route_counts.items()))
     print(f"[{name}] kernel backend: {dispatch.resolve(None)}")
     print(f"[{name}] kernel routes: {routes or 'none'}")
-    print(f"[{name}] backend compiles: {clock.count - compiles0} taking "
-          f"{clock.seconds - compile_s0:.6f} s")
+    compiles, compile_s = tracing.compiles()
+    print(f"[{name}] backend compiles: {compiles - compiles0} taking "
+          f"{compile_s - compile_s0:.6f} s")
     print(f"[{name}] first round incl. set-up and compile: "
           f"{round_s[0]:.6f} s")
     if len(round_s) > 1:
@@ -318,24 +303,23 @@ def main(argv=None) -> None:
           f"default kernel backend resolves to {backend!r}, not 'pallas'")
     print(f"device: {devices[0].device_kind} x{len(devices)}; compile "
           f"cache: {enable_compile_cache()}", flush=True)
-    clock = CompileClock()
     common = ["--method", "edgefd", "--scenario", "strong", "--engine",
               "cohort", "--clients", "10", "--rounds", "3",
               "--seed", str(args.seed)]
 
     if args.four_chips:
         feat = [*common, "--dataset", "mnist_feat"]
-        base = run_phase("mnist_feat", feat, devices[0], clock,
+        base = run_phase("mnist_feat", feat, devices[0],
                          min_acc=MNIST_FEAT_MIN_ACC)
         mesh = run_phase("mnist_feat --devices 4", [*feat, "--devices", "4"],
-                         devices[0], clock, min_acc=MNIST_FEAT_MIN_ACC)
+                         devices[0], min_acc=MNIST_FEAT_MIN_ACC)
         # both comparisons print their gaps before either may fail
         problems = compare_logs("1-D client mesh", base, mesh, MESH_1D_TOL)
         lm = [*common, "--dataset", "lm_tokens"]
-        base = run_phase("lm_tokens", lm, devices[0], clock)
+        base = run_phase("lm_tokens", lm, devices[0])
         mesh = run_phase("lm_tokens --devices 4 --model-shards 2",
                          [*lm, "--devices", "4", "--model-shards", "2"],
-                         devices[0], clock)
+                         devices[0])
         problems += compare_logs("2-D (clients, model) mesh", base, mesh,
                                  MESH_2D_TOL)
         check(not problems, "; ".join(problems))
@@ -344,9 +328,9 @@ def main(argv=None) -> None:
               flush=True)
         check_kernels(args.seed)
         run_phase("cifar_like", [*common, "--dataset", "cifar_like",
-                                 "--n-train", "50000"], devices[0], clock)
+                                 "--n-train", "50000"], devices[0])
         run_phase("mnist_feat", [*common, "--dataset", "mnist_feat"],
-                  devices[0], clock, min_acc=MNIST_FEAT_MIN_ACC)
+                  devices[0], min_acc=MNIST_FEAT_MIN_ACC)
 
     print(json.dumps({"ok": True, "device": {
         "platform": devices[0].platform, "kind": devices[0].device_kind,

@@ -65,6 +65,11 @@ class RoundLog:
     # per-phase host wall-clock breakdown (repro.fed.scheduler phase nodes;
     # wall_s is their sum)
     phase_s: Dict[str, float] = dataclasses.field(default_factory=dict)
+    # what the round's phase nodes did beyond their time
+    # (repro.common.tracing): device->host reads of the engine
+    # ("engine.syncs") and of the server ("server.syncs"), and programs
+    # JAX built ("compiles")
+    counters: Dict[str, int] = dataclasses.field(default_factory=dict)
     # when this round retired on the simulated straggler timeline
     # (repro.fed.clock) — the axis on which round_mode="overlap" beats
     # "sync"; see benchmarks/async_rounds.py

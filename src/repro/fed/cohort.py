@@ -81,6 +81,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common import tracing
+from repro.common.tracing import fetch
 from repro.core import distill as D
 from repro.core.dre import KMeansDRE, KuLSIFDRE
 from repro.core.kmeans import kmeans_fit_batched, min_dist_to_centroids
@@ -227,11 +229,13 @@ class _Cohort:
         self._pack_learned_filter_state()
 
     # ----------------------------------------------------- mesh placement
+    @tracing.stage
     def _put_c(self, tree):
         """Place leaves with the leading client axis split over the mesh."""
         return shard_clients(jax.tree.map(jnp.asarray, tree),
                              self.mesh, self.mesh_axis)
 
+    @tracing.stage
     def _put_rep(self, tree):
         """Place leaves replicated on every mesh device (shared inputs)."""
         return replicate(jax.tree.map(jnp.asarray, tree), self.mesh)
@@ -267,6 +271,7 @@ class _Cohort:
         for lo in range(0, c, self.wave_size):
             yield lo, min(lo + self.wave_size, c)
 
+    @tracing.stage
     def _stage(self, arr, lo: int, hi: int, fill=0):
         """Stage host rows ``[lo, hi)`` as a ``(c_pad, ...)`` device-ready
         array. Rows past ``hi - lo`` are dummy lanes: ``fill`` is a pad
@@ -284,6 +289,7 @@ class _Cohort:
         out[:n] = arr[lo:hi]
         return out
 
+    @tracing.stage
     def _stage_state(self, lo: int, hi: int):
         """One wave's params/opt-state, staged host -> device."""
         pd = self._put_state(jax.tree.map(
@@ -297,12 +303,11 @@ class _Cohort:
         masters (dummy rows dropped); the device buffers die with their
         last reference when the next wave stages."""
         n = hi - lo
-        jax.tree.map(
-            lambda h, d: h.__setitem__(slice(lo, hi), np.asarray(d)[:n]),
-            self._hparams, params_dev)
-        jax.tree.map(
-            lambda h, d: h.__setitem__(slice(lo, hi), np.asarray(d)[:n]),
-            self._hopt, opt_dev)
+        params_h, opt_h = fetch((params_dev, opt_dev))
+        jax.tree.map(lambda h, d: h.__setitem__(slice(lo, hi), d[:n]),
+                     self._hparams, params_h)
+        jax.tree.map(lambda h, d: h.__setitem__(slice(lo, hi), d[:n]),
+                     self._hopt, opt_h)
 
     def _ctx(self):
         """Logical-rules scope for every jitted call: inside it the logical
@@ -360,11 +365,13 @@ class _Cohort:
                 lambda leaf, sh: jax.lax.with_sharding_constraint(leaf, sh),
                 tree, shardings)
 
-        def pinned(fn, state_out: bool = False):
+        def pinned(fn, name: str, state_out: bool = False):
             """jit(fn) with every output pinned to the client axis (no-op
-            when traced without a mesh in scope — see ``_ctx``).
-            ``state_out`` marks fns returning (params, opt_state, losses):
-            their state outputs take the per-leaf client × model specs."""
+            when traced without a mesh in scope — see ``_ctx``), compiled
+            as ``jit_cohort_<name>`` and launched in a ``cohort.launch``
+            span. ``state_out`` marks fns returning (params, opt_state,
+            losses): their state outputs take the per-leaf client × model
+            specs."""
             def wrapped(*args):
                 out = fn(*args)
                 if state_out:
@@ -373,7 +380,8 @@ class _Cohort:
                             pin_state(opt_state, o_sh),
                             pin_clients(losses))
                 return pin_clients(out)
-            return jax.jit(wrapped)
+            wrapped.__name__ = wrapped.__qualname__ = "cohort_" + name
+            return tracing.launched(jax.jit(wrapped))
 
         def scan_steps(batch_loss):
             """Shared scan skeleton: grad step + validity gating; the three
@@ -450,21 +458,23 @@ class _Cohort:
                                       (xb, yb, mb))
             return correct
 
-        self._train = pinned(vmap(train_chunk), state_out=True)
+        self._train = pinned(vmap(train_chunk), "train", state_out=True)
         self._distill = pinned(
             vmap(distill_chunk, in_axes=(0, 0, None, None, 0, 0, 0)),
-            state_out=True)
+            "distill", state_out=True)
         self._distill_private = pinned(
             vmap(distill_private_chunk,
                      in_axes=(0, 0, 0, 0, None, None, 0, 0, 0)),
-            state_out=True)
+            "distill_private", state_out=True)
         self._predict = pinned(
-            vmap(lambda p, xb: apply_fn(p, xb, False), in_axes=(0, None)))
+            vmap(lambda p, xb: apply_fn(p, xb, False), in_axes=(0, None)),
+            "predict")
         self._eval = pinned(
-            vmap(eval_chunk, in_axes=(0, None, None, None)))
-        self._classwise = pinned(vmap(classwise_chunk))
+            vmap(eval_chunk, in_axes=(0, None, None, None)), "eval")
+        self._classwise = pinned(vmap(classwise_chunk), "classwise")
         self._kmeans_masks = pinned(
-            vmap(kmeans_mask_chunk, in_axes=(0, 0, 0, None, None)))
+            vmap(kmeans_mask_chunk, in_axes=(0, 0, 0, None, None)),
+            "kmeans_masks")
 
         def kulsif_mask_chunk(alpha, aux, priv, n, thr, cid, sigma, lam,
                               pxf, owner):
@@ -477,7 +487,8 @@ class _Cohort:
 
         self._kulsif_masks = pinned(
             vmap(kulsif_mask_chunk,
-                     in_axes=(0, 0, 0, 0, 0, 0, None, None, None, None)))
+                     in_axes=(0, 0, 0, 0, 0, 0, None, None, None, None)),
+            "kulsif_masks")
 
     # -------------------------------------------------------------- DRE learn
     @staticmethod
@@ -693,6 +704,7 @@ class _Cohort:
             self._pack_filter_state()
 
     # ----------------------------------------------------------- round phases
+    @tracing.spanned("cohort.plan")
     def _plan(self, draw_n: int, epochs: int, batch_size: int,
               weight=None, part=None
               ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -745,7 +757,7 @@ class _Cohort:
                 self.params, self.opt_state, losses = self._train(
                     self.params, self.opt_state, self.x, self.y,
                     self._put_c(idx), self._put_c(w), self._put_c(valid))
-            return self._mean_losses(np.asarray(losses)[:C], valid[:C])
+            return self._mean_losses(fetch(losses)[:C], valid[:C])
         losses_h = np.zeros((C, valid.shape[1]), np.float32)
         for lo, hi in self._waves():
             pd, od = self._stage_state(lo, hi)
@@ -758,7 +770,7 @@ class _Cohort:
                     self._put_c(self._stage(w, lo, hi)),
                     self._put_c(self._stage(valid, lo, hi)))
             self._write_state(pd, od, lo, hi)
-            losses_h[lo:hi] = np.asarray(losses)[: hi - lo]
+            losses_h[lo:hi] = fetch(losses)[: hi - lo]
         return self._mean_losses(losses_h, valid[:C])
 
     def distill(self, px, teacher, weight, epochs: int,
@@ -772,7 +784,7 @@ class _Cohort:
                     self.params, self.opt_state,
                     self._put_rep(px), self._put_rep(teacher),
                     self._put_c(idx), self._put_c(w), self._put_c(valid))
-            return self._mean_losses(np.asarray(losses)[:C], valid[:C])
+            return self._mean_losses(fetch(losses)[:C], valid[:C])
         pxd, td = self._put_rep(px), self._put_rep(teacher)  # shared by waves
         losses_h = np.zeros((C, valid.shape[1]), np.float32)
         for lo, hi in self._waves():
@@ -784,7 +796,7 @@ class _Cohort:
                     self._put_c(self._stage(w, lo, hi)),
                     self._put_c(self._stage(valid, lo, hi)))
             self._write_state(pd, od, lo, hi)
-            losses_h[lo:hi] = np.asarray(losses)[: hi - lo]
+            losses_h[lo:hi] = fetch(losses)[: hi - lo]
         return self._mean_losses(losses_h, valid[:C])
 
     def distill_private(self, teacher_by_class, valid_by_class, epochs: int,
@@ -798,7 +810,7 @@ class _Cohort:
                     self._put_rep(teacher_by_class),
                     self._put_rep(np.asarray(valid_by_class, np.float32)),
                     self._put_c(idx), self._put_c(w), self._put_c(valid))
-            return self._mean_losses(np.asarray(losses)[:C], valid[:C])
+            return self._mean_losses(fetch(losses)[:C], valid[:C])
         td = self._put_rep(teacher_by_class)
         vd = self._put_rep(np.asarray(valid_by_class, np.float32))
         losses_h = np.zeros((C, valid.shape[1]), np.float32)
@@ -814,7 +826,7 @@ class _Cohort:
                     self._put_c(self._stage(w, lo, hi)),
                     self._put_c(self._stage(valid, lo, hi)))
             self._write_state(pd, od, lo, hi)
-            losses_h[lo:hi] = np.asarray(losses)[: hi - lo]
+            losses_h[lo:hi] = fetch(losses)[: hi - lo]
         return self._mean_losses(losses_h, valid[:C])
 
     def classwise_means(self, part=None):
@@ -822,7 +834,7 @@ class _Cohort:
             with self._ctx():
                 means, counts = self._classwise(self.params, self.x, self.y,
                                                 self.sample_mask)
-            means, counts = np.asarray(means), np.asarray(counts)
+            means, counts = fetch((means, counts))
         else:
             C = len(self.members)
             means = np.zeros((C, self.num_classes, self.num_classes),
@@ -836,8 +848,9 @@ class _Cohort:
                         self._put_c(self._stage(self._hx, lo, hi)),
                         self._put_c(self._stage(self._hy, lo, hi)),
                         self._put_c(self._stage(self._hm, lo, hi)))
-                means[lo:hi] = np.asarray(m_w)[: hi - lo]
-                counts[lo:hi] = np.asarray(c_w)[: hi - lo]
+                m_w, c_w = fetch((m_w, c_w))
+                means[lo:hi] = m_w[: hi - lo]
+                counts[lo:hi] = c_w[: hi - lo]
         if part is not None:
             # sampled-out members report nothing (zero counts drop them
             # from the classwise fuse exactly like the loop engine's skip)
@@ -850,7 +863,7 @@ class _Cohort:
         if not self._waved:
             with self._ctx():
                 out = self._predict(self.params, self._put_rep(px))
-            out = np.asarray(out)[: len(self.members)]
+            out = fetch(out)[: len(self.members)]
         else:
             C = len(self.members)
             pxd = self._put_rep(px)
@@ -859,7 +872,7 @@ class _Cohort:
                 pd, _ = self._stage_state(lo, hi)
                 with self._ctx():
                     o_w = self._predict(pd, pxd)
-                out[lo:hi] = np.asarray(o_w)[: hi - lo]
+                out[lo:hi] = fetch(o_w)[: hi - lo]
         if part is not None:
             out = out.copy()
             out[~np.asarray(part, bool)] = 0.0
@@ -887,7 +900,7 @@ class _Cohort:
             # all-True masks (sampled-out members are skipped, again like
             # the loop engine)
             return np.stack([
-                np.asarray(c.filter_mask(px, powner).mask)
+                fetch(c.filter_mask(px, powner).mask)
                 if part is None or part[i] else np.zeros((t,), bool)
                 for i, c in enumerate(self.members)])
         pxf = self._put_rep(np.asarray(px).reshape(t, -1))
@@ -908,7 +921,7 @@ class _Cohort:
                                                st["thresholds"], cids,
                                                st["sigma"], st["lam"],
                                                pxf, owner)
-            return gated(np.asarray(masks)[: len(self.members)])
+            return gated(fetch(masks)[: len(self.members)])
         # waved: filter state lives host-side, staged one wave at a time.
         # Pad fills keep dummy lanes inert where they feed real math: cid
         # -1 never owns, kulsif n=1.0 never divides by zero, private rows
@@ -935,7 +948,7 @@ class _Cohort:
                         self._put_c(self._stage(st["thresholds"], lo, hi)),
                         cids, jnp.float32(st["sigma"]),
                         jnp.float32(st["lam"]), pxf, owner)
-            out[lo:hi] = np.asarray(masks)[: hi - lo]
+            out[lo:hi] = fetch(masks)[: hi - lo]
         return gated(out)
 
     def evaluate(self, x_test, y_test, batch_size: int = 512) -> List[float]:
@@ -960,14 +973,14 @@ class _Cohort:
             with self._ctx():
                 correct = self._eval(self.params, xb, yb, mb)
             return [int(c) / n
-                    for c in np.asarray(correct)[: len(self.members)]]
+                    for c in fetch(correct)[: len(self.members)]]
         C = len(self.members)
         correct = np.zeros((C,), np.int64)
         for lo, hi in self._waves():
             pd, _ = self._stage_state(lo, hi)
             with self._ctx():
                 c_w = self._eval(pd, xb, yb, mb)
-            correct[lo:hi] = np.asarray(c_w)[: hi - lo]
+            correct[lo:hi] = fetch(c_w)[: hi - lo]
         return [int(c) / n for c in correct]
 
     def sync_to_clients(self) -> None:

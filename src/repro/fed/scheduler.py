@@ -38,8 +38,10 @@ policy degenerates to the lockstep order; under ``overlap`` it interleaves
 rounds like a software pipeline. The policy is engine-independent, so loop
 == cohort == mesh-sharded round logs still match under ``overlap``.
 
-Every node execution is timed (``RoundLog.phase_s``) and priced onto the
-simulated straggler timeline (``repro.fed.clock``): clients run in
+Every node execution is timed (``RoundLog.phase_s``, from its
+``phase.<name>`` span; ``repro.common.tracing``), its sync and compile
+counts are booked on its round (``RoundLog.counters``), and it is priced
+onto the simulated straggler timeline (``repro.fed.clock``): clients run in
 parallel at deterministic per-client speeds, the server is one serial
 resource, and ``RoundLog.sim_finish_s`` records when the round retires on
 that timeline. That is the axis on which overlap measurably beats sync on
@@ -73,11 +75,11 @@ checkpointable like every other phase.
 from __future__ import annotations
 
 import os
-import time
 from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro.common import tracing
 from repro.core.protocol import RoundLog
 from repro.fed.clock import (ARRIVAL_PROCESSES, SimTimeline, arrival_offsets,
                              client_speeds, dropout_mask, online_mask)
@@ -188,13 +190,21 @@ def _entry(engine, phase_name: str, legacy_name: str) -> Callable:
     return fn if fn is not None else getattr(engine, legacy_name)
 
 
+def _node_meta(key: Tuple) -> Dict[str, int]:
+    """Span metadata of a node: its round, and its cohort if it has one."""
+    if len(key) > 2:
+        return {"round": key[1], "cohort": key[2]}
+    return {"round": key[1]}
+
+
 class _RoundState:
     """Mutable state threaded between one round's phase nodes."""
 
     __slots__ = ("r", "part", "kw", "idx", "px", "powner", "means_counts",
                  "teacher", "valid", "teacher_by_class", "valid_by_class",
                  "local_losses", "distill_losses", "id_frac", "id_fracs",
-                 "mean_staleness", "accs", "phase_s", "sim_finish_s",
+                 "mean_staleness", "accs", "phase_s", "counters",
+                 "sim_finish_s",
                  "report_payload", "rpart", "sampled", "reports_pending",
                  "report_logits", "report_masks", "report_arrival",
                  "server_distill_loss", "server_student_acc")
@@ -219,6 +229,7 @@ class _RoundState:
         self.mean_staleness = 0.0
         self.accs = None
         self.phase_s: Dict[str, float] = {}
+        self.counters: Dict[str, int] = {}
         self.sim_finish_s = 0.0
         # (logits, masks) parked between the report body and the
         # post-pricing ingest event; consumed within the same node
@@ -274,6 +285,7 @@ class _RoundState:
             "accs": (None if self.accs is None
                      else [float(a) for a in self.accs]),
             "phase_s": {k: float(v) for k, v in self.phase_s.items()},
+            "counters": {k: int(v) for k, v in self.counters.items()},
             "sim_finish_s": float(self.sim_finish_s),
             "rpart": opt_array(self.rpart, bool),
             "sampled": bool(self.sampled),
@@ -311,6 +323,8 @@ class _RoundState:
         accs = sd["accs"]
         self.accs = None if accs is None else [float(a) for a in accs]
         self.phase_s = {k: float(v) for k, v in sd["phase_s"].items()}
+        self.counters = {k: int(v)
+                         for k, v in sd.get("counters", {}).items()}
         self.sim_finish_s = float(sd["sim_finish_s"])
         # concurrent-cohort / ensemble-server fields (``.get``: absent from
         # checkpoints written before these features existed — the defaults
@@ -552,36 +566,38 @@ class RoundScheduler:
         (or crash at: the kill-and-resume harness keys off these)."""
         if not self._pending:
             raise RuntimeError("no pending nodes — call begin() first")
-        ready = [
-            k for k in self._pending
-            if all(d[1] not in self._states or d[:-1] in self._done
-                   for d in self._nodes[k])
-        ]
-        # deterministic pipeline policy: front (client-side) phases
-        # before drain phases, oldest round first, intra-round order
-        # next, cohort index last — under sync with one cohort exactly one
-        # node is ever ready, so this replays the legacy lockstep order
-        key = min(ready, key=lambda k: (k[0] not in FRONT_PHASES, k[1],
-                                        self._order[k[0]],
-                                        k[2] if len(k) > 2 else -1))
-        phase, r = key[0], key[1]
-        self._run_node(key, self._states[r], self._nodes[key])
-        self._pending.remove(key)
-        self._done.add(key)
-        log = None
-        if phase == self.phases[-1]:
-            log = self._finish_round(self._states[r])
-            if self._watchdog and self._wd_unhealthy(log) \
-                    and self._wd_rollback(r):
-                # the round was replayed from the last healthy retire; the
-                # sick log is discarded and the caller sees no retirement
-                return phase, r, None
-            self.logs.append(log)
-            self.completed += 1
-            self._retire(r)
-            if self._watchdog:
-                self._wd_note_healthy(log)
-        return phase, r, log
+        with tracing.mark("sched.step") as sp:
+            ready = [
+                k for k in self._pending
+                if all(d[1] not in self._states or d[:-1] in self._done
+                       for d in self._nodes[k])
+            ]
+            # deterministic pipeline policy: front (client-side) phases
+            # before drain phases, oldest round first, intra-round order
+            # next, cohort index last — under sync with one cohort exactly one
+            # node is ever ready, so this replays the legacy lockstep order
+            key = min(ready, key=lambda k: (k[0] not in FRONT_PHASES, k[1],
+                                            self._order[k[0]],
+                                            k[2] if len(k) > 2 else -1))
+            phase, r = key[0], key[1]
+            sp.set_metadata(**_node_meta(key))
+            self._run_node(key, self._states[r], self._nodes[key])
+            self._pending.remove(key)
+            self._done.add(key)
+            log = None
+            if phase == self.phases[-1]:
+                log = self._finish_round(self._states[r])
+                if self._watchdog and self._wd_unhealthy(log) \
+                        and self._wd_rollback(r):
+                    # the round was replayed from the last healthy retire; the
+                    # sick log is discarded and the caller sees no retirement
+                    return phase, r, None
+                self.logs.append(log)
+                self.completed += 1
+                self._retire(r)
+                if self._watchdog:
+                    self._wd_note_healthy(log)
+            return phase, r, log
 
     def drain(self, progress: Optional[Callable[[RoundLog], None]] = None
               ) -> List[RoundLog]:
@@ -809,14 +825,15 @@ class RoundScheduler:
     def _run_node(self, key: Tuple, st: _RoundState, deps) -> None:
         phase = key[0]
         self.trace.append(key)
-        t0 = time.perf_counter()
-        if len(key) > 2:  # per-cohort client node (concurrent mode)
-            getattr(self, "_phase_" + phase + "_cohort")(st, key[2])
-        else:
-            getattr(self, "_phase_" + phase)(st)
-        dt = time.perf_counter() - t0
-        st.phase_s[phase] = st.phase_s.get(phase, 0.0) + dt
-        self._account(key, st, deps, dt)
+        meta = _node_meta(key)
+        before = tracing.counts()
+        with tracing.span("phase." + phase, **meta) as sp:
+            if len(key) > 2:  # per-cohort client node (concurrent mode)
+                getattr(self, "_phase_" + phase + "_cohort")(st, key[2])
+            else:
+                getattr(self, "_phase_" + phase)(st)
+        st.phase_s[phase] = st.phase_s.get(phase, 0.0) + sp.s
+        self._account(key, st, deps, sp.s)
         if phase == "report":
             # ingestion is an *event* driven by the arrival-trace clock: it
             # runs after the node is priced so each report's simulated
@@ -824,9 +841,10 @@ class RoundScheduler:
             # admission can replay them in arrival order. In concurrent
             # mode _ingest_reports no-ops until the round's LAST report
             # node has accumulated and priced its cohort's rows.
-            t0 = time.perf_counter()
-            self._ingest_reports(st)
-            st.phase_s[phase] += time.perf_counter() - t0
+            with tracing.span("server.ingest", **meta) as sp:
+                self._ingest_reports(st)
+            st.phase_s[phase] += sp.s
+        tracing.book(st.counters, before)
 
     def _report_part(self, st: _RoundState):
         """The round's *reporting* participants: serial mode mutates
@@ -1197,6 +1215,7 @@ class RoundScheduler:
                           else [int(i) for i in np.flatnonzero(part)]),
             mean_staleness=st.mean_staleness,
             phase_s=dict(st.phase_s),
+            counters=dict(st.counters),
             sim_finish_s=st.sim_finish_s,
             served_model_age_s=age,
             server_distill_loss=st.server_distill_loss,

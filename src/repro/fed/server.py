@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common import tracing
+from repro.common.tracing import fetch
 from repro.core import aggregation
 from repro.core import distill as D
 from repro.core.filtering import server_entropy_filter
@@ -84,15 +86,15 @@ class _ServerStudent:
                 self.params, self.opt_state, loss = self._distill_step(
                     self.params, self.opt_state, jnp.asarray(px[idx]),
                     jnp.asarray(teacher[idx]), jnp.asarray(weight[idx]))
-                losses.append(float(loss))
+                losses.append(float(fetch(loss, "server")))
         return float(np.mean(losses)) if losses else 0.0
 
     def evaluate(self, x_test, y_test, batch_size: int = 512) -> float:
         hits = 0
         for lo in range(0, len(y_test), batch_size):
             xb = jnp.asarray(x_test[lo:lo + batch_size])
-            preds = np.asarray(jnp.argmax(self._predict(self.params, xb),
-                                          axis=-1))
+            preds = fetch(jnp.argmax(self._predict(self.params, xb),
+                                     axis=-1), "server")
             hits += int((preds == np.asarray(y_test[lo:lo + batch_size]))
                         .sum())
         return hits / max(len(y_test), 1)
@@ -495,8 +497,8 @@ class Server:
                 ages_sum += merged.ages_sum
                 n_contrib += merged.num_contributing
             if entropy_filter:  # per-client-row filter — shard-local is exact
-                m_e = np.asarray(server_entropy_filter(
-                    jnp.asarray(l_e), jnp.asarray(m_e)))
+                m_e = fetch(server_entropy_filter(
+                    jnp.asarray(l_e), jnp.asarray(m_e)), "server")
             if robust:
                 # robust modes use staleness weights only as a
                 # contribute/exclude mask (one vote per surviving client)
@@ -504,7 +506,7 @@ class Server:
                 t_e, _ = aggregation.robust_reduce(
                     jnp.asarray(l_e), jnp.asarray(m_r),
                     self.robust_aggregation, trim_frac=self.trim_frac)
-                center = np.asarray(t_e)
+                center = fetch(t_e, "server")
                 cnt = m_r.sum(axis=0).astype(np.float32)      # (t,)
                 num, den = center * cnt[:, None], cnt
             else:
@@ -513,7 +515,7 @@ class Server:
                     jnp.asarray(l_e), jnp.asarray(m_e),
                     None if cw is None else jnp.asarray(cw),
                     guard_finite=self.sanitize)
-                num, den = np.asarray(num), np.asarray(den)
+                num, den = fetch((num, den), "server")
                 center = None
             if self.track_outliers:
                 if center is None:
@@ -530,6 +532,7 @@ class Server:
                                 uploaded_bytes, mean_staleness,
                                 outlier, contrib)
 
+    @tracing.spanned("server.aggregate")
     def aggregate_round(self, round_idx: int, *,
                         sharpen: Optional[float] = None,
                         entropy_filter: bool = False):
@@ -558,8 +561,7 @@ class Server:
                 teacher.shape[-1]) * 4
             if self.track_outliers and p.outlier is not None:
                 self._update_trust(round_idx, p.outlier, p.contrib)
-            return (np.asarray(teacher), np.asarray(valid),
-                    p.mean_staleness)
+            return (*fetch((teacher, valid), "server"), p.mean_staleness)
         if p.merged is None:
             teacher, valid = self.aggregate(p.logits, p.masks,
                                             sharpen=sharpen,
@@ -626,10 +628,11 @@ class Server:
         k = logits.shape[-1]
         up = (uploaded_masks if uploaded_rows is None
               else uploaded_masks[np.asarray(uploaded_rows, bool)])
-        self.bytes_received += int(jnp.sum(up)) * k * 4
+        self.bytes_received += int(fetch(jnp.sum(up), "server")) * k * 4
         self.bytes_broadcast += int(teacher.shape[0]) * k * 4
-        return np.asarray(teacher), np.asarray(valid)
+        return fetch((teacher, valid), "server")
 
+    @tracing.spanned("server.aggregate")
     def aggregate_classwise(self, means_counts, *, count_weighted: bool,
                             uploaded_rows=None,
                             round_idx: Optional[int] = None):
@@ -653,8 +656,8 @@ class Server:
         means = jnp.stack([m for m, _ in means_counts])     # (C, K_cls, K)
         counts = jnp.stack([c for _, c in means_counts])    # (C, K_cls)
         if self.sanitize:
-            mn = np.asarray(means, np.float32)
-            cn = np.asarray(counts)
+            mn, cn = fetch((means, counts), "server")
+            mn = np.asarray(mn, np.float32)
             fin = np.isfinite(mn).all(axis=-1)               # (C, K_cls)
             if not fin.all():
                 per_client = ((cn > 0) & ~fin).sum(axis=1).astype(np.int64)
@@ -694,7 +697,7 @@ class Server:
         # like the proxy-logit teacher in ``aggregate`` (this path used to
         # report zero download traffic for FKD/PLS data-free rounds)
         self.bytes_broadcast += int(np.prod(teacher.shape)) * 4
-        return np.asarray(teacher), np.asarray(valid)
+        return fetch((teacher, valid), "server")
 
     # ------------------------------------------------- resumable service
     def state_dict(self) -> dict:

@@ -307,6 +307,12 @@ def print_round(log, num_clients: int) -> None:
             for k, v in log.phase_s.items())
         extra += (f"  sim={log.sim_finish_s:.2f}s"
                   f"  age={log.served_model_age_s:.2f}s  [{breakdown}]")
+    n = log.counters
+    if n:
+        extra += (f"  syncs={n.get('engine.syncs', 0)}"
+                  f"+{n.get('server.syncs', 0)}")
+        if n.get("compiles", 0):
+            extra += f"  compiles={n['compiles']}"
     print(f"round {log.round:3d}  acc={log.mean_acc:.4f}  "
           f"id={log.id_fraction:.2f}  local={log.local_loss:.3f}  "
           f"distill={log.distill_loss:.3f}  "

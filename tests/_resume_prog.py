@@ -21,9 +21,10 @@ import dataclasses
 # deterministic sim pricing so the timeline fields are comparable
 FIXED_COSTS = {"local_train": 1.0, "report": 0.1, "aggregate": 0.3,
                "distill": 1.0, "eval": 0.0}
-# host-measured wall-clock can never match across runs; everything else
-# must be bit-for-bit
-MEASURED_FIELDS = ("wall_s", "phase_s")
+# host-measured wall-clock and the programs a process happened to build
+# ("counters.compiles": a resumed process builds its own) can never match
+# across runs; everything else must be bit-for-bit
+MEASURED_FIELDS = ("wall_s", "phase_s", "counters.compiles")
 
 
 def _cfg(engine: str, devices: int, round_mode: str, **kw):
@@ -53,9 +54,19 @@ def build_sched(cfg, dataset: str = "mnist_feat"):
                           sim_phase_costs=FIXED_COSTS)
 
 
+def strip_measured(d: dict) -> dict:
+    """A round log's dict without its ``MEASURED_FIELDS`` (``a.b`` names
+    key ``b`` of the dict field ``a``)."""
+    out = {k: v for k, v in d.items() if k not in MEASURED_FIELDS}
+    for name in MEASURED_FIELDS:
+        field, _, key = name.partition(".")
+        if key and field in out:
+            out[field] = {k: v for k, v in out[field].items() if k != key}
+    return out
+
+
 def strip(logs):
-    return [{k: v for k, v in dataclasses.asdict(lg).items()
-             if k not in MEASURED_FIELDS} for lg in logs]
+    return [strip_measured(dataclasses.asdict(lg)) for lg in logs]
 
 
 def check_resume(engine: str, devices: int, round_mode: str,

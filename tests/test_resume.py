@@ -115,9 +115,7 @@ def test_fed_serve_sigkill_resume(tmp_path):
 
     def load(p):
         with open(p) as f:
-            return [{k: v for k, v in d.items()
-                     if k not in _resume_prog.MEASURED_FIELDS}
-                    for d in json.load(f)]
+            return [_resume_prog.strip_measured(d) for d in json.load(f)]
     assert load(tmp_path / "resumed.json") == load(tmp_path / "ref.json")
 
 
