@@ -138,12 +138,13 @@ def launched(jitted) -> Callable:
 
 
 def fetch(x, side: str = "engine"):
-    """``x`` (an array or a tree of them) read to the host with
-    ``np.asarray``, inside ``cohort.fetch`` (``side="engine"``) or
-    ``server.fetch`` (``side="server"``), counted as one sync."""
+    """``x`` (an array or a tree of them) read to the host as numpy,
+    inside ``cohort.fetch`` (``side="engine"``) or ``server.fetch``
+    (``side="server"``), counted as one sync. A tree's copies all start
+    before the first is waited for."""
     name, counter = _FETCH[side]
     _totals[counter] += 1
     with TraceAnnotation(name, **_meta):
         if isinstance(x, jax.Array):
             return np.asarray(x)
-        return jax.tree.map(np.asarray, x)
+        return jax.device_get(x)

@@ -11,6 +11,11 @@ reducer (including the plain mean) guards against non-finite client rows: a
 single inf/NaN logit from a diverged client must never poison the fused
 teacher (the guard is an exact no-op on finite inputs, so the legacy logs
 stay bit-for-bit).
+
+The single-tier server (``repro.fed.server.server_aggregate``) traces
+these reducers, with the server-side filter, the sharpening and the upload
+count, into one compiled program read back once; on the CPU it equals the
+eager calls bit for bit.
 """
 from __future__ import annotations
 
@@ -32,6 +37,19 @@ def _finite_rows(logits, mask):
     lo = jnp.asarray(logits, jnp.float32)
     fin = jnp.isfinite(lo).all(axis=-1)                      # (C, t)
     return jnp.where(fin[..., None], lo, 0.0), fin
+
+
+def sharpen_logits(teacher, temperature):
+    """DS-FL's entropy reduction: the log of ``softmax(teacher / T)``,
+    floored at 1e-12. ``temperature`` may be traced."""
+    probs = jax.nn.softmax(teacher / temperature, axis=-1)
+    return jnp.log(jnp.maximum(probs, 1e-12))
+
+
+def _sharpen(teacher, temperature_sharpen: Optional[float]):
+    if temperature_sharpen:
+        return sharpen_logits(teacher, temperature_sharpen)
+    return teacher
 
 
 def masked_mean_logits(logits, mask, *, temperature_sharpen: Optional[float] = None,
@@ -56,10 +74,7 @@ def masked_mean_logits(logits, mask, *, temperature_sharpen: Optional[float] = N
     cnt = jnp.sum(m, axis=0)                                 # (t, 1)
     teacher = s / jnp.maximum(cnt, 1.0)
     valid = cnt[..., 0] > 0.0
-    if temperature_sharpen:
-        probs = jax.nn.softmax(teacher / temperature_sharpen, axis=-1)
-        teacher = jnp.log(jnp.maximum(probs, 1e-12))         # sharpened logits
-    return teacher, valid
+    return _sharpen(teacher, temperature_sharpen), valid
 
 
 def weighted_masked_mean_logits(logits, mask, client_weights, *,
@@ -89,10 +104,7 @@ def weighted_masked_mean_logits(logits, mask, client_weights, *,
     # — matching the unweighted form.
     teacher = s / jnp.where(den > 0.0, den, 1.0)
     valid = den[..., 0] > 0.0
-    if temperature_sharpen:
-        probs = jax.nn.softmax(teacher / temperature_sharpen, axis=-1)
-        teacher = jnp.log(jnp.maximum(probs, 1e-12))         # sharpened logits
-    return teacher, valid
+    return _sharpen(teacher, temperature_sharpen), valid
 
 
 def partial_masked_sums(logits, mask, client_weights=None, *,
@@ -130,10 +142,7 @@ def fuse_partial_sums(nums, dens, *,
     den = jnp.sum(jnp.asarray(dens, jnp.float32), axis=0)    # (t,)
     teacher = s / jnp.where(den > 0.0, den, 1.0)[..., None]
     valid = den > 0.0
-    if temperature_sharpen:
-        probs = jax.nn.softmax(teacher / temperature_sharpen, axis=-1)
-        teacher = jnp.log(jnp.maximum(probs, 1e-12))         # sharpened logits
-    return teacher, valid
+    return _sharpen(teacher, temperature_sharpen), valid
 
 
 def masked_mean_logits_psum(local_logits, local_mask, axis_name: str = "data"):
@@ -164,13 +173,6 @@ def classwise_mean_logits(logits, labels, num_classes: int):
 # ---------------------------------------------------------------------------
 # Robust reducers over the client axis
 # ---------------------------------------------------------------------------
-
-def _sharpen(teacher, temperature_sharpen):
-    if temperature_sharpen:
-        probs = jax.nn.softmax(teacher / temperature_sharpen, axis=-1)
-        teacher = jnp.log(jnp.maximum(probs, 1e-12))
-    return teacher
-
 
 def _sorted_valid(logits, mask):
     """Sort each (t, K) coordinate over the client axis with invalid

@@ -22,6 +22,7 @@ what lets ``benchmarks/scale.py`` push C to 16k on a laptop-class host.
 legacy aggregation and byte accounting."""
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, NamedTuple, Optional, Union
 
 import jax
@@ -144,6 +145,52 @@ class _PendingPartials(NamedTuple):
     # contributing mask, computed at ingest since the stack dies here
     outlier: Optional[np.ndarray] = None    # (C,) float
     contrib: Optional[np.ndarray] = None    # (C,) bool
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "mode", "trim_frac", "entropy_filter", "guard_finite"))
+def server_aggregate(logits, masks, client_weights, uploaded_rows, sharpen,
+                     *, mode: str, trim_frac: float, entropy_filter: bool,
+                     guard_finite: bool):
+    """The single-tier server reduce as one program.
+
+    logits: (C, t, K); masks: (C, t) bool; ``client_weights``: (C,)
+    staleness weights, or None for fresh reports; ``uploaded_rows``: (C,)
+    bool, the clients that uploaded this round, or None for all;
+    ``sharpen``: DS-FL's temperature, or None. The temperature is an
+    operand, not a constant: XLA would turn a division by a constant into
+    a product with its reciprocal, which rounds differently.
+
+    Returns ``(teacher (t, K), valid (t,), uploaded)``. ``uploaded``
+    counts the ID rows the uploaders sent from the *pre-filter* masks:
+    that is what crossed the network, and the server-side filter only
+    tightens what the reduce uses."""
+    if uploaded_rows is not None:
+        uploaded = jnp.sum(jnp.logical_and(masks, uploaded_rows[:, None]))
+    else:
+        uploaded = jnp.sum(masks)
+    if entropy_filter:  # Selective-FD baseline's extra server stage
+        masks = server_entropy_filter(logits, masks)
+    if mode != "mean":
+        # robust order statistics have no fractional voters: staleness
+        # weights act only as a contribute/exclude mask here
+        if client_weights is not None:
+            masks = jnp.logical_and(masks, (client_weights > 0.0)[:, None])
+        teacher, valid = aggregation.robust_reduce(
+            logits, masks, mode, trim_frac=trim_frac)
+    elif client_weights is not None:
+        teacher, valid = aggregation.weighted_masked_mean_logits(
+            logits, masks, client_weights, guard_finite=guard_finite)
+    else:
+        teacher, valid = aggregation.masked_mean_logits(
+            logits, masks, guard_finite=guard_finite)
+    if sharpen is not None:
+        # the barrier keeps XLA from folding the mean's divide into the
+        # temperature's ((s / n) / T into s / (n * T)), which rounds
+        # differently from the reducers' own sharpening
+        teacher = aggregation.sharpen_logits(
+            jax.lax.optimization_barrier(teacher), sharpen)
+    return teacher, valid, uploaded
 
 
 # EWMA trust scores for non-finite senders are pinned here instead of inf
@@ -594,43 +641,29 @@ class Server:
         the plain masked-mean path, bit-for-bit the legacy teacher).
         ``uploaded_rows`` (C,) restricts the upload accounting to clients
         that actually reported this round: stale reuse costs no bytes.
+
+        The filter, the reduce and the upload count run as one compiled
+        program (``server_aggregate``) read back in a single fetch; the
+        host keeps only the byte arithmetic.
         """
-        logits = jnp.asarray(logits)
-        masks = jnp.asarray(masks)
-        # clients uploaded the *pre-filter* ID rows — snapshot them before
-        # the server-side filter tightens the masks, so bytes_received
-        # prices what actually crossed the network (the filtered masks
-        # undercounted the Selective-FD baseline's uploads)
-        uploaded_masks = masks
-        if entropy_filter:  # Selective-FD baseline's extra server stage
-            masks = server_entropy_filter(logits, masks)
         cw = (None if client_weights is None
               else np.asarray(client_weights, np.float32))
-        if self.robust_aggregation != "mean":
-            # robust order statistics have no fractional voters: staleness
-            # weights act only as a contribute/exclude mask here
-            m_r = (masks if cw is None
-                   else jnp.logical_and(masks,
-                                        jnp.asarray(cw > 0.0)[:, None]))
-            teacher, valid = aggregation.robust_reduce(
-                logits, m_r, self.robust_aggregation,
-                trim_frac=self.trim_frac, temperature_sharpen=sharpen)
-        elif cw is not None and not bool(np.all(cw == 1.0)):
-            teacher, valid = aggregation.weighted_masked_mean_logits(
-                logits, masks, jnp.asarray(cw), temperature_sharpen=sharpen,
-                guard_finite=self.sanitize)
-        else:
-            teacher, valid = aggregation.masked_mean_logits(
-                logits, masks, temperature_sharpen=sharpen,
-                guard_finite=self.sanitize)
+        if (cw is not None and self.robust_aggregation == "mean"
+                and np.all(cw == 1.0)):
+            cw = None
+        rows = (None if uploaded_rows is None
+                else np.asarray(uploaded_rows, bool))
+        teacher, valid, uploaded = fetch(server_aggregate(
+            logits, masks, cw, rows, sharpen or None,
+            mode=self.robust_aggregation, trim_frac=self.trim_frac,
+            entropy_filter=entropy_filter, guard_finite=self.sanitize),
+            "server")
         # accounting: clients upload only ID logits (mask-compressed), and
         # only the round's participants upload at all
-        k = logits.shape[-1]
-        up = (uploaded_masks if uploaded_rows is None
-              else uploaded_masks[np.asarray(uploaded_rows, bool)])
-        self.bytes_received += int(fetch(jnp.sum(up), "server")) * k * 4
+        k = teacher.shape[-1]
+        self.bytes_received += int(uploaded) * k * 4
         self.bytes_broadcast += int(teacher.shape[0]) * k * 4
-        return fetch((teacher, valid), "server")
+        return teacher, valid
 
     @tracing.spanned("server.aggregate")
     def aggregate_classwise(self, means_counts, *, count_weighted: bool,

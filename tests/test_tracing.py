@@ -4,7 +4,7 @@ On a C=4 cohort round: the program's spans nest as the module's table
 says and carry their round; ``RoundLog.counters`` counts the device->host
 reads the round makes and the programs JAX built in it; ``phase_s`` keeps
 its meaning; every cohort program compiles under its ``jit_cohort_*``
-name.
+name, and the server's reduce as ``jit_server_aggregate``.
 """
 import dataclasses
 import glob
@@ -14,6 +14,7 @@ import pytest
 
 from repro.common.types import FedConfig
 from repro.core.methods import get_method
+from repro.fed import server as server_mod
 from repro.fed import simulator
 from repro.fed.scheduler import RoundScheduler, round_phases
 
@@ -25,10 +26,10 @@ PROGRAMS = {"_train": "train", "_distill": "distill",
             "_kmeans_masks": "kmeans_masks", "_kulsif_masks": "kulsif_masks"}
 # device->host reads of one sync edgefd round over one C=4 cohort: the
 # engine reads the training losses, the proxy logits, the filter masks,
-# the distillation losses and the eval counts; the server reads the
-# uploaded-row count and then (teacher, valid) in one read
+# the distillation losses and the eval counts; the server reads
+# (teacher, valid, uploaded-row count) of its one compiled reduce in one read
 EDGEFD_ENGINE_SYNCS = 5
-EDGEFD_SERVER_SYNCS = 2
+EDGEFD_SERVER_SYNCS = 1
 
 
 def _sched(method="edgefd", engine="cohort", **kw):
@@ -144,25 +145,33 @@ def test_loop_engine_books_server_syncs_only():
 
 
 @pytest.mark.parametrize("method", ["edgefd", "fkd", "selective-fd"])
-def test_cohort_programs_lower_under_their_names(method):
+def test_cohort_programs_lower_under_their_names(method, monkeypatch):
     sched = _sched(method)
     seen = {}
+    program = server_mod.server_aggregate
+
+    def record_server(*args, **kwargs):
+        seen["server"] = (program, args, kwargs)
+        return program(*args, **kwargs)
+    monkeypatch.setattr(server_mod, "server_aggregate", record_server)
     for c in sched.engine.cohorts:
         for attr in PROGRAMS:
             fn = getattr(c, attr)
 
             def record(*args, fn=fn, attr=attr):
-                seen[attr] = (fn, args)
+                seen[attr] = (fn, args, {})
                 return fn(*args)
             setattr(c, attr, record)
     _round(sched, 0)
     want = {"edgefd": {"_train", "_predict", "_kmeans_masks", "_distill",
-                       "_eval"},
+                       "_eval", "server"},
             "fkd": {"_train", "_classwise", "_distill_private", "_eval"},
             "selective-fd": {"_train", "_predict", "_kulsif_masks",
-                             "_distill", "_eval"}}[method]
+                             "_distill", "_eval", "server"}}[method]
     assert set(seen) == want
-    for attr, (fn, args) in seen.items():
-        text = fn.lower(*args).as_text()
-        assert f"@jit_cohort_{PROGRAMS[attr]}" in text, attr
+    for attr, (fn, args, kwargs) in seen.items():
+        text = fn.lower(*args, **kwargs).as_text()
+        name = ("jit_server_aggregate" if attr == "server"
+                else f"jit_cohort_{PROGRAMS[attr]}")
+        assert f"@{name}" in text, attr
         assert "jit_wrapped" not in text
