@@ -37,7 +37,7 @@ def main(argv=None) -> int:
 
     import jax.numpy as jnp
 
-    from fdbench import compare, fleetdata, harness, reference
+    from fdbench import compare, harness, reference
 
     cell = harness.load_cell(root, args.workload)
     config = cell.config
@@ -64,7 +64,8 @@ def main(argv=None) -> int:
         t0 = time.perf_counter()
         c0 = counter.mark()
         split = {}
-        data, prog = harness.set_up(config, traffic, seed, split)
+        data, prog = harness.set_up(config, traffic, seed, split,
+                                    cell.cell["chips"])
         readings = harness.follow(prog)
         compiles = counter.since(c0)
         del prog
@@ -82,9 +83,7 @@ def main(argv=None) -> int:
                   flush=True)
         dump()
     for seed in seeds(args.control_seeds):
-        data = fleetdata.make_dataset(
-            config["dataset"], traffic["num_clients"]
-            * traffic["samples_per_client"], traffic["n_test"], seed)
+        data = harness.make_data(config, traffic, seed)
         t0 = time.perf_counter()
         ref = reference.run(config, traffic, data, seed)
         t1 = time.perf_counter()

@@ -1,76 +1,27 @@
 """Operations and bytes counted from the configuration's declared shapes.
 
 Nothing here reads the program or the compiler's cost analysis: a model's
-FLOPs come from the layer lists in ``configs/*.json``, and the distill-KL
-kernel's FLOPs and bytes from each call's ``(n, K)``.
+FLOPs come from its model kind's module (``models/<kind>.py``) and the
+shapes ``configs/*.json`` declare, and the distill-KL kernel's FLOPs and
+bytes from each call's ``(n, K)``.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Tuple
+from typing import List, Tuple
+
+from fdbench import kinds
 
 
-def arch_layers(config: dict, arch: int) -> List[list]:
-    """The declared layer list of one client architecture."""
-    if config["model"] == "cnn_zoo":
-        return config["archs"][arch % len(config["archs"])]
-    dims = [config["input"]["feature_dim"], *config["hidden"],
-            config["num_classes"]]
-    return [["linear", d] for d in dims[1:]]
-
-
-def param_shapes(config: dict, arch: int) -> List[Dict[str, Tuple[int, ...]]]:
-    """Per-layer parameter shapes the declared layers imply, in the
-    program's layout: conv ``w`` (k, k, c_in, c_out), ``b`` (c_out,); BN
-    ``scale``/``bias``/``mean``/``var`` (c,); linear ``w`` (d_in, d_out),
-    ``b`` (d_out,)."""
-    inp = config["input"]
-    if config["model"] == "cnn_zoo":
-        h = inp["image_hw"]
-        c = inp["channels"]
-        flat = None
-    else:
-        h, c, flat = 0, inp["feature_dim"], inp["feature_dim"]
-    out = []
-    for layer in arch_layers(config, arch):
-        kind = layer[0]
-        if kind == "conv":
-            _, cout, k, pool, pad = layer
-            out.append({"w": (k, k, c, cout), "b": (cout,)})
-            if pad != "SAME":
-                h = h - k + 1
-            if pool:
-                h //= 2
-            c = cout
-            flat = h * h * c
-        elif kind == "bn":
-            out.append({n: (c,) for n in ("bias", "mean", "scale", "var")})
-        elif kind == "linear":
-            d_out = layer[1]
-            out.append({"w": (flat, d_out), "b": (d_out,)})
-            flat = d_out
-        else:
-            raise ValueError(f"unknown layer kind {kind!r}")
-    return out
+def param_shapes(config: dict, arch: int):
+    """Client ``arch``'s parameter shapes in the program's layout, as its
+    model kind declares them (``models/<kind>.py``)."""
+    return kinds.load(config).param_shapes(config, arch)
 
 
 def forward_flops(config: dict, arch: int) -> int:
-    """Multiply-adds x 2 of one sample's forward pass through the convs and
-    linears (activations, pooling and BN are not counted)."""
-    inp = config["input"]
-    h = inp.get("image_hw", 0)
-    total = 0
-    for layer, shapes in zip(arch_layers(config, arch),
-                             param_shapes(config, arch)):
-        if layer[0] == "conv":
-            _, cout, k, pool, pad = layer
-            h_out = h if pad == "SAME" else h - k + 1
-            k_, _, cin, _ = shapes["w"]
-            total += 2 * h_out * h_out * k_ * k_ * cin * cout
-            h = h_out // 2 if pool else h_out
-        elif layer[0] == "linear":
-            d_in, d_out = shapes["w"]
-            total += 2 * d_in * d_out
-    return total
+    """FLOPs of one sample's forward pass through client ``arch``'s model,
+    as its model kind counts them."""
+    return kinds.load(config).forward_flops(config, arch)
 
 
 def round_flops(config: dict, traffic: dict, cohort_sizes: List[int],
@@ -90,9 +41,7 @@ def round_flops(config: dict, traffic: dict, cohort_sizes: List[int],
     t = traffic["proxy_batch"]
     distill_rows = (t // k_batch) * k_batch if t >= k_batch else t
     part = traffic.get("participation_fraction", 1.0)
-    inp = config["input"]
-    d = (inp["image_hw"] ** 2 * inp["channels"] if "image_hw" in inp
-         else inp["feature_dim"])
+    d = kinds.load(config).filter_dim(config)
     total = 0.0
     cid = 0
     for size in cohort_sizes:

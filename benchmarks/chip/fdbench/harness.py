@@ -3,13 +3,15 @@ the comparison with the reference.
 
 Everything about a cell is found by name: ``BENCHMARK.json`` names the
 cell's configuration and traffic; ``configs/<config>.json``,
-``traffic/<traffic>.json`` and ``limits/<cell>.json`` hold them, and
-``metrics/<metric>.py`` reads each per-layer metric.
+``traffic/<traffic>.json`` and ``limits/<cell>.json`` hold them,
+``models/<model>.py`` holds what depends on the configuration's kind of
+client model (``fdbench.kinds``), and ``metrics/<metric>.py`` reads each
+per-layer metric.
 """
 from __future__ import annotations
 
+import dataclasses
 import gc
-import importlib.util
 import json
 import math
 import shutil
@@ -20,6 +22,9 @@ from pathlib import Path
 from types import SimpleNamespace
 from typing import Callable, Dict, List, Optional
 
+from fdbench import kinds
+from fdbench.kinds import Refused
+
 HERE = Path(__file__).resolve().parents[1]          # benchmarks/chip
 # JAX records this around every program it builds, also when it reads the
 # program from the persistent cache; a read records CACHE_HIT_EVENT as well
@@ -28,10 +33,9 @@ CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 TRACE_MIN_ROUNDS = 3
 TRACE_MIN_SECONDS = 2.0
 FOLLOWED_ROUNDS = 3
-
-
-class Refused(RuntimeError):
-    """The run cannot give a result (no chip, unknown device, bad cell)."""
+# traffic keys the harness reads itself; every other traffic key is a
+# field of the program's FedConfig
+HARNESS_KEYS = ("samples_per_client", "n_test", "source", "assumed")
 
 
 class CompileCounter:
@@ -88,6 +92,7 @@ def load_cell(root: Path, name: str) -> SimpleNamespace:
     cell = cells[name]
     configs = {c["name"]: c for c in bench["configs"]}
     config = load_json(root / configs[cell["config"]]["file"])
+    kinds.load(config)
     traffic = load_json(HERE / "traffic" / f"{cell['traffic']}.json")
     limits = load_json(HERE / "limits" / f"{name}.json")
 
@@ -101,27 +106,24 @@ def load_cell(root: Path, name: str) -> SimpleNamespace:
 
 
 def load_reader(metric: str) -> Callable:
-    path = HERE / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        "fdbench_metric_" + metric.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return kinds.load_file(HERE / "metrics" / f"{metric}.py",
+                           "fdbench_metric_" + metric.replace(".", "_")).read
 
 
 # --------------------------------------------------------------- the program
 def fed_config(traffic: Dict, seed: int):
+    """The program's ``FedConfig``: every traffic key that names one of its
+    fields, and the run's seed. A key that is neither a field nor one of
+    ``HARNESS_KEYS`` is refused, so that a misspelt knob cannot measure
+    the default."""
     from repro.common.types import FedConfig
-    return FedConfig(
-        num_clients=traffic["num_clients"], method=traffic["method"],
-        scenario=traffic["scenario"], local_epochs=traffic["local_epochs"],
-        distill_epochs=traffic["distill_epochs"],
-        proxy_fraction=traffic["proxy_fraction"],
-        proxy_batch=traffic["proxy_batch"], batch_size=traffic["batch_size"],
-        lr=traffic["lr"], temperature=traffic["temperature"],
-        participation_fraction=traffic["participation_fraction"],
-        engine=traffic["engine"], round_mode=traffic["round_mode"],
-        seed=seed)
+    fields = {f.name for f in dataclasses.fields(FedConfig)} - {"seed"}
+    unknown = sorted(set(traffic) - fields - set(HARNESS_KEYS))
+    if unknown:
+        raise Refused(f"traffic keys {unknown} are neither FedConfig fields "
+                      f"nor the harness's {list(HARNESS_KEYS)}")
+    return FedConfig(**{k: v for k, v in traffic.items() if k in fields},
+                     seed=seed)
 
 
 def build(config: Dict, traffic: Dict, data, seed: int) -> SimpleNamespace:
@@ -141,11 +143,9 @@ def build(config: Dict, traffic: Dict, data, seed: int) -> SimpleNamespace:
     own = simulator.make_dataset
     simulator.make_dataset = lambda *a, **k: data
     try:
-        widths = ({"mlp_hidden": tuple(config["hidden"])}
-                  if config["model"] == "mlp" else {})
         clients, server, x_test, y_test = simulator.build_experiment(
             cfg, config["dataset"]["name"], n_train=len(data.y),
-            n_test=len(data.y_test), **widths)
+            n_test=len(data.y_test), **kinds.load(config).build_kwargs(config))
     finally:
         simulator.make_dataset = own
     engine = engine_from_config(clients, cfg)
@@ -166,13 +166,15 @@ def block(engine) -> None:
 
 
 def member_leaves(engine, tree_of: Callable) -> Dict[int, List]:
-    """Per client id, its leaves in the reference's order (layer by layer,
-    keys sorted), sliced out of the cohorts' stacked trees."""
+    """Per client id, its leaves sliced out of the cohorts' stacked trees,
+    in ``jax.tree_util``'s order as the reference takes them (for a list of
+    per-layer dicts: layer by layer, keys sorted)."""
+    import jax
     out = {}
     for c in engine.cohorts:
-        tree = tree_of(c)
+        leaves = jax.tree_util.tree_leaves(tree_of(c))
         for j, cid in enumerate(c.positions):
-            out[cid] = [layer[k][j] for layer in tree for k in sorted(layer)]
+            out[cid] = [leaf[j] for leaf in leaves]
     return out
 
 
@@ -184,16 +186,37 @@ def leaf_norms(leaves: Dict[int, List]) -> List[float]:
         [jnp.linalg.norm(jnp.ravel(a).astype(jnp.float32)) for a in flat])]
 
 
+def _is_shape(x) -> bool:
+    return isinstance(x, tuple) and all(isinstance(d, int) for d in x)
+
+
 def check_shapes(engine, config: Dict) -> None:
+    """Every client's parameters have the shapes, at the paths, that its
+    model kind declares."""
+    from jax.tree_util import keystr, tree_flatten_with_path
+
     from fdbench import flops
     for c in engine.cohorts:
-        for j, cid in enumerate(c.positions):
-            want = flops.param_shapes(config, cid)
-            got = [{k: tuple(v.shape[1:]) for k, v in layer.items()}
-                   for layer in c.params]
+        got = [(keystr(p), tuple(v.shape[1:]))
+               for p, v in tree_flatten_with_path(c.params)[0]]
+        for cid in c.positions:
+            want = [(keystr(p), tuple(s)) for p, s in tree_flatten_with_path(
+                flops.param_shapes(config, cid), is_leaf=_is_shape)[0]]
             if got != want:
                 raise Refused(f"client {cid}'s parameters {got} differ from "
                               f"the configuration's declared {want}")
+
+
+def check_placement(engine, chips: int) -> None:
+    """The cohorts' parameters span at least the cell's ``chips`` devices,
+    so that a cell on four chips cannot measure one."""
+    import jax
+    spanned = {d for c in engine.cohorts
+               for leaf in jax.tree_util.tree_leaves(c.params)
+               for d in leaf.devices()}
+    if len(spanned) < chips:
+        raise Refused(f"the cell asks for {chips} chips, the cohorts' "
+                      f"parameters span {len(spanned)} device(s)")
 
 
 def run_round(sched, r: int, on_step: Optional[Callable] = None):
@@ -241,17 +264,26 @@ def follow(prog) -> Dict:
     return readings
 
 
-def set_up(config: Dict, traffic: Dict, seed: int, split: Dict):
-    """Data, the program's build and DRE fit; returns (data, program)."""
+def make_data(config: Dict, traffic: Dict, seed: int):
+    """The cell's data from the seed, made by the model kind's
+    ``make_dataset`` where it has one."""
     from fdbench import fleetdata
+    make = getattr(kinds.load(config), "make_dataset",
+                   fleetdata.make_dataset)
+    return make(config["dataset"], traffic["num_clients"]
+                * traffic["samples_per_client"], traffic["n_test"], seed)
+
+
+def set_up(config: Dict, traffic: Dict, seed: int, split: Dict,
+           chips: int):
+    """Data, the program's build and DRE fit; returns (data, program)."""
     t0 = time.perf_counter()
-    data = fleetdata.make_dataset(
-        config["dataset"], traffic["num_clients"]
-        * traffic["samples_per_client"], traffic["n_test"], seed)
+    data = make_data(config, traffic, seed)
     split["data_s"] = time.perf_counter() - t0
     t0 = time.perf_counter()
     prog = build(config, traffic, data, seed)
     check_shapes(prog.engine, config)
+    check_placement(prog.engine, chips)
     split["build_s"] = time.perf_counter() - t0 - prog.dre_s
     split["dre_s"] = prog.dre_s
     return data, prog
@@ -305,7 +337,7 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
     traffic = dict(cell.traffic, **(traffic_overrides or {}))
     config = cell.config
     split = {}
-    data, prog = set_up(config, traffic, seed, split)
+    data, prog = set_up(config, traffic, seed, split, cell.cell["chips"])
 
     # the followed rounds: the same engine and scheduler the window drives
     t0 = time.perf_counter()

@@ -7,10 +7,9 @@ program:
 * the non-IID partition (strong: one class per client; IID: a uniform
   split) and the proxy set (a fraction of each client's data, shuffled),
   with NumPy generators seeded as the protocol seeds them;
-* each client's model (the configuration's layer list), initialized from
-  ``PRNGKey(seed)`` split once per client and once per layer, He-normal
-  convs and LeCun-normal linears with zero biases, BN scale 1, bias 0 and
-  stored statistics 0 and 1;
+* each client's model, as the configuration's model kind declares it
+  (``models/<kind>.py``), initialized from ``PRNGKey(seed)`` split once
+  per client;
 * the KMeans-DRE filter: k-means++ seeding from ``fold_in(key, client)``,
   50 Lloyd iterations with a 1e-6 shift tolerance, the threshold at the
   0.95 quantile of the client's own distances; a proxy row is kept when
@@ -19,21 +18,20 @@ program:
   batches on cross-entropy, the server's proxy-batch draw, eval-mode
   logits on it, the masked-mean teacher, distillation by
   ``T^2 KL(teacher_T || student_T)`` weighted by the teacher's validity,
-  and test accuracy;
-* BN uses batch statistics in training and its stored statistics in
-  inference, as the configuration states (the stored ones never move).
+  and test accuracy.
 
 All of it runs in ``dtype`` with matmuls and convolutions at ``precision``:
 float32 at ``highest`` is the reference; bfloat16 is the control.
 """
 from __future__ import annotations
 
-import math
 from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from fdbench import kinds
 
 MOMENTUM = 0.9
 KMEANS_ITERS = 50
@@ -79,80 +77,9 @@ def proxy_set(clients, fraction: float, seed: int):
 
 
 # ---------------------------------------------------------------- the models
-def layer_list(config: dict, cid: int) -> List[list]:
-    if config["model"] == "cnn_zoo":
-        return config["archs"][cid % len(config["archs"])]
-    dims = [config["input"]["feature_dim"], *config["hidden"],
-            config["num_classes"]]
-    return [["linear", d] for d in dims[1:]]
-
-
 def init_params(key, config: dict, cid: int):
-    inp = config["input"]
-    if config["model"] == "mlp":
-        dims = [inp["feature_dim"], *config["hidden"], config["num_classes"]]
-        params = []
-        for d_in, d_out in zip(dims[:-1], dims[1:]):
-            key, sub = jax.random.split(key)
-            w = jax.random.normal(sub, (d_in, d_out)) * (1.0 / math.sqrt(d_in))
-            params.append({"w": w, "b": jnp.zeros((d_out,))})
-        return params
-    h, c, flat = inp["image_hw"], inp["channels"], None
-    params = []
-    for layer in layer_list(config, cid):
-        key, sub = jax.random.split(key)
-        if layer[0] == "conv":
-            _, cout, k, pool, pad = layer
-            std = math.sqrt(2.0 / (c * k * k))
-            params.append({"w": jax.random.normal(sub, (k, k, c, cout)) * std,
-                           "b": jnp.zeros((cout,))})
-            h = h if pad == "SAME" else h - k + 1
-            h = h // 2 if pool else h
-            c = cout
-            flat = h * h * c
-        elif layer[0] == "bn":
-            params.append({"scale": jnp.ones((c,)), "bias": jnp.zeros((c,)),
-                           "mean": jnp.zeros((c,)), "var": jnp.ones((c,))})
-        else:
-            d_out = layer[1]
-            params.append({"w": jax.random.normal(sub, (flat, d_out))
-                           * (1.0 / math.sqrt(flat)),
-                           "b": jnp.zeros((d_out,))})
-            flat = d_out
-    return params
-
-
-def make_apply(layers: List[list], num_classes: int, precision):
-    def apply(params, x, train: bool):
-        flat = False
-        for layer, p in zip(layers, params):
-            if layer[0] == "conv":
-                x = jax.lax.conv_general_dilated(
-                    x, p["w"], (1, 1), layer[4],
-                    dimension_numbers=("NHWC", "HWIO", "NHWC"),
-                    precision=precision) + p["b"]
-                x = jax.nn.relu(x)
-                if layer[3]:
-                    x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max,
-                                              (1, 2, 2, 1), (1, 2, 2, 1),
-                                              "VALID")
-            elif layer[0] == "bn":
-                if train:
-                    mean = jnp.mean(x, axis=(0, 1, 2))
-                    var = jnp.var(x, axis=(0, 1, 2))
-                else:
-                    mean, var = p["mean"], p["var"]
-                x = (x - mean) * jax.lax.rsqrt(var + 1e-5) * p["scale"] \
-                    + p["bias"]
-            else:
-                if not flat:
-                    x = x.reshape(x.shape[0], -1)
-                    flat = True
-                x = jnp.dot(x, p["w"], precision=precision) + p["b"]
-                if layer[1] != num_classes:
-                    x = jax.nn.relu(x)
-        return x
-    return apply
+    """Client ``cid``'s initial parameters, as its model kind makes them."""
+    return kinds.load(config).init_params(key, config, cid)
 
 
 # ------------------------------------------------------------------ the filter
@@ -209,9 +136,10 @@ def _filter_mask(cents, thr, px_flat, powner, cid):
 
 
 # ------------------------------------------------------------------ the steps
-def _make_fns(layers, num_classes: int, lr: float, temperature: float,
-              dtype, precision, fault: Optional[str]):
-    apply = make_apply(layers, num_classes, precision)
+def _make_fns(apply, lr: float, temperature: float, dtype,
+              fault: Optional[str]):
+    """The jitted train, distill, logits and correct-count functions of one
+    forward function ``apply(params, x, train)``."""
 
     def sgd_scan(loss_fn, params, mu, batches):
         def step(carry, batch):
@@ -265,9 +193,18 @@ def _make_fns(layers, num_classes: int, lr: float, temperature: float,
     return train, distill, logits, correct
 
 
+def _inputs(x, dtype) -> jax.Array:
+    """Model inputs on the device: features and images in ``dtype``;
+    integer inputs, such as token ids, as they are."""
+    x = np.asarray(x)
+    return jnp.asarray(x, dtype if np.issubdtype(x.dtype, np.floating)
+                       else None)
+
+
 def _leaves(params) -> List[jax.Array]:
-    """Leaves in a fixed order: layer by layer, keys sorted."""
-    return [layer[k] for layer in params for k in sorted(layer)]
+    """Leaves in ``jax.tree_util``'s order, the harness's too: for a list
+    of per-layer dicts, layer by layer with the keys sorted."""
+    return jax.tree_util.tree_leaves(params)
 
 
 def _norms(leaves) -> np.ndarray:
@@ -291,23 +228,23 @@ def run(config: dict, traffic: dict, data, seed: int, *, rounds: int = 3,
     px_all, powner_all = proxy_set(clients, traffic["proxy_fraction"], seed)
     n_centroids = 1 if traffic["scenario"] == "strong" else k_cls
 
+    model = kinds.load(config)
     key = jax.random.PRNGKey(seed)
     params, mus, fns, xs, ys, rngs, filters = [], [], [], [], [], [], []
     fn_cache: Dict = {}
     for cid, (x, y) in enumerate(clients):
         key, sub = jax.random.split(key)
-        p = jax.tree.map(lambda a: a.astype(dtype), init_params(sub, config,
-                                                                 cid))
+        p = jax.tree.map(lambda a: a.astype(dtype),
+                         model.init_params(sub, config, cid))
         params.append(p)
         mus.append(jax.tree.map(jnp.zeros_like, p))
-        layers = layer_list(config, cid)
-        arch = repr(layers)
+        arch = model.arch_key(config, cid)
         if arch not in fn_cache:
-            fn_cache[arch] = _make_fns(layers, k_cls, traffic["lr"],
-                                       traffic["temperature"], dtype,
-                                       precision, fault)
+            fn_cache[arch] = _make_fns(
+                model.make_apply(config, cid, precision), traffic["lr"],
+                traffic["temperature"], dtype, fault)
         fns.append(fn_cache[arch])
-        xs.append(jnp.asarray(x, dtype))
+        xs.append(_inputs(x, dtype))
         ys.append(jnp.asarray(y))
         rngs.append(np.random.default_rng(seed + 1000 * cid))
     dre_key = jax.random.PRNGKey(seed)
@@ -338,7 +275,7 @@ def run(config: dict, traffic: dict, data, seed: int, *, rounds: int = 3,
         sel = server_rng.choice(len(powner_all),
                                 size=min(traffic["proxy_batch"],
                                          len(powner_all)), replace=False)
-        px = jnp.asarray(px_all[sel], dtype)
+        px = _inputs(px_all[sel], dtype)
         powner = jnp.asarray(powner_all[sel])
         px_flat = jnp.asarray(px_all[sel].reshape(len(sel), -1))
         lg, mk = [], []
@@ -365,7 +302,7 @@ def run(config: dict, traffic: dict, data, seed: int, *, rounds: int = 3,
             c = 0
             for s in range(0, n_test, EVAL_BATCH):
                 c += fns[cid][3](params[cid],
-                                 jnp.asarray(x_test[s:s + EVAL_BATCH], dtype),
+                                 _inputs(x_test[s:s + EVAL_BATCH], dtype),
                                  y_test[s:s + EVAL_BATCH])
             accs.append(c)
         local, dist, accs, fr = jax.device_get(

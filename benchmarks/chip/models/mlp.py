@@ -1,0 +1,39 @@
+"""The client MLP of a feature-mode configuration: linear layers
+``input.feature_dim`` -> each of ``hidden`` -> ``num_classes`` with ReLU
+between them, the same for every client (``fdbench.layers``)."""
+from fdbench import layers
+
+
+def layer_list(config: dict, cid: int) -> list:
+    dims = [config["input"]["feature_dim"], *config["hidden"],
+            config["num_classes"]]
+    return [["linear", d] for d in dims[1:]]
+
+
+def param_shapes(config: dict, cid: int):
+    return layers.param_shapes(layer_list(config, cid), config["input"])
+
+
+def forward_flops(config: dict, cid: int) -> int:
+    return layers.forward_flops(layer_list(config, cid), config["input"])
+
+
+def filter_dim(config: dict) -> int:
+    return layers.filter_dim(config["input"])
+
+
+def init_params(key, config: dict, cid: int):
+    return layers.init_params(key, layer_list(config, cid), config["input"])
+
+
+def make_apply(config: dict, cid: int, precision):
+    return layers.make_apply(layer_list(config, cid), config["num_classes"],
+                             precision)
+
+
+def arch_key(config: dict, cid: int) -> str:
+    return repr(layer_list(config, cid))
+
+
+def build_kwargs(config: dict) -> dict:
+    return {"mlp_hidden": tuple(config["hidden"])}
