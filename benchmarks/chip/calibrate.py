@@ -35,6 +35,7 @@ def main(argv=None) -> int:
     sys.path.insert(0, str(root / "src"))
     import gc
 
+    import jax
     import jax.numpy as jnp
 
     from fdbench import compare, harness, reference
@@ -42,6 +43,7 @@ def main(argv=None) -> int:
     cell = harness.load_cell(root, args.workload)
     config = cell.config
     traffic = cell.traffic
+    devices = jax.devices()[:cell.cell["chips"]]
     harness.enable_cache()
     counter = harness.CompileCounter.get()
     out = {"workload": args.workload, "program": {}, "control": {},
@@ -71,7 +73,7 @@ def main(argv=None) -> int:
         del prog
         gc.collect()
         t1 = time.perf_counter()
-        ref = reference.run(config, traffic, data, seed)
+        ref = reference.run(config, traffic, data, seed, devices=devices)
         nums = compare.numbers(readings, ref)
         out["program"][seed] = {"numbers": nums, "program": readings,
                                 "reference": ref}
@@ -85,15 +87,16 @@ def main(argv=None) -> int:
     for seed in seeds(args.control_seeds):
         data = harness.make_data(config, traffic, seed)
         t0 = time.perf_counter()
-        ref = reference.run(config, traffic, data, seed)
+        ref = reference.run(config, traffic, data, seed, devices=devices)
         t1 = time.perf_counter()
-        ctrl = reference.run(config, traffic, data, seed, dtype=jnp.bfloat16,
-                             precision=None)
+        ctrl = reference.run(config, traffic, data, seed, devices=devices,
+                             dtype=jnp.bfloat16, precision=None)
         nums = compare.numbers(ctrl, ref)
         out["control"][seed] = {"numbers": nums}
         note("control", seed, nums, f" (reference {t1 - t0:.1f} s)")
         for fault in reference.FAULTS:
-            bad = reference.run(config, traffic, data, seed, fault=fault)
+            bad = reference.run(config, traffic, data, seed,
+                                devices=devices, fault=fault)
             nums = compare.numbers(bad, ref)
             out["faults"].setdefault(fault, {})[seed] = {"numbers": nums}
             note(f"fault {fault}", seed, nums)

@@ -20,7 +20,7 @@ import sys
 import time
 from pathlib import Path
 from types import SimpleNamespace
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
 from fdbench import kinds
 from fdbench.kinds import Refused
@@ -165,25 +165,34 @@ def block(engine) -> None:
         jax.block_until_ready((c.params, c.opt_state))
 
 
-def member_leaves(engine, tree_of: Callable) -> Dict[int, List]:
-    """Per client id, its leaves sliced out of the cohorts' stacked trees,
-    in ``jax.tree_util``'s order as the reference takes them (for a list of
-    per-layer dicts: layer by layer, keys sorted)."""
+def member_leaves(engine, tree_of: Callable) -> Iterator[Tuple[int, List]]:
+    """Each client id with its leaves sliced out of the cohorts' stacked
+    trees, in ``jax.tree_util``'s order as the reference takes them (for a
+    list of per-layer dicts: layer by layer, keys sorted). One client at a
+    time: a slice of a cohort split over chips lands on every chip."""
     import jax
-    out = {}
     for c in engine.cohorts:
         leaves = jax.tree_util.tree_leaves(tree_of(c))
         for j, cid in enumerate(c.positions):
-            out[cid] = [leaf[j] for leaf in leaves]
-    return out
+            yield cid, [leaf[j] for leaf in leaves]
 
 
-def leaf_norms(leaves: Dict[int, List]) -> List[float]:
+def leaf_norms(engine, tree_of: Callable,
+               minus: Optional[Dict[int, List]] = None) -> List[float]:
+    """The norm of every leaf of every client, by client id; of each leaf
+    less its leaf in ``minus[cid]`` where given. Each client is read
+    before the next is sliced."""
     import jax
     import jax.numpy as jnp
-    flat = [a for cid in sorted(leaves) for a in leaves[cid]]
-    return [float(v) for v in jax.device_get(
-        [jnp.linalg.norm(jnp.ravel(a).astype(jnp.float32)) for a in flat])]
+    norms = {}
+    for cid, leaves in member_leaves(engine, tree_of):
+        if minus is not None:
+            leaves = [a - jax.device_put(b, a.sharding)
+                      for a, b in zip(leaves, minus[cid])]
+        norms[cid] = jax.device_get(
+            [jnp.linalg.norm(jnp.ravel(a).astype(jnp.float32))
+             for a in leaves])
+    return [float(v) for cid in sorted(norms) for v in norms[cid]]
 
 
 def _is_shape(x) -> bool:
@@ -242,8 +251,13 @@ def follow(prog) -> Dict:
     clients' accuracies and ID fractions, the per-leaf momentum norms
     after round 0 and the per-leaf change of the parameters over the
     rounds."""
+    import jax
     engine, sched = prog.engine, prog.sched
-    p0 = member_leaves(engine, lambda c: c.params)
+    # the starting point, each client's on one of the chips its cohort spans
+    p0 = {}
+    for cid, leaves in member_leaves(engine, lambda c: c.params):
+        chips = sorted(leaves[0].devices(), key=lambda d: d.id)
+        p0[cid] = jax.device_put(leaves, chips[cid % len(chips)])
     readings = {"local_loss": [], "distill_loss": [], "accs": [],
                 "id_fracs": []}
     for r in range(FOLLOWED_ROUNDS):
@@ -254,12 +268,10 @@ def follow(prog) -> Dict:
         readings["id_fracs"].append(list(lg.client_id_fractions))
         if r == 0:
             readings["grad_norms"] = leaf_norms(
-                member_leaves(engine, lambda c: c.opt_state["mu"]))
-    now = member_leaves(engine, lambda c: c.params)
-    readings["change_norms"] = leaf_norms(
-        {cid: [a - b for a, b in zip(now[cid], p0[cid])] for cid in now})
-    readings["leaf_shapes"] = [list(a.shape) for cid in sorted(now)
-                               for a in now[cid]]
+                engine, lambda c: c.opt_state["mu"])
+    readings["change_norms"] = leaf_norms(engine, lambda c: c.params, p0)
+    readings["leaf_shapes"] = [list(a.shape) for cid in sorted(p0)
+                               for a in p0[cid]]
     block(engine)
     return readings
 
@@ -370,7 +382,7 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
     round_s = window_s / len(walls)
     ctx = SimpleNamespace(
         rounds=rounds, round_s=round_s, peaks=peaks, config=config,
-        traffic=traffic, trace=None, phases=None,
+        traffic=traffic, trace=None, phases=None, chips=cell.cell["chips"],
         round_flops=flops.round_flops(
             config, traffic, [len(c.members) for c in engine.cohorts],
             1 if traffic["scenario"] == "strong" else config["num_classes"]))
@@ -418,7 +430,9 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
     del engine, sched, prog, logs
     gc.collect()
     t0 = time.perf_counter()
-    ref = reference.run(config, traffic, data, seed, rounds=FOLLOWED_ROUNDS)
+    ref = reference.run(config, traffic, data, seed,
+                        devices=devices[:cell.cell["chips"]],
+                        rounds=FOLLOWED_ROUNDS)
     if readings_source is not None:
         readings = readings_source(config, traffic, data, seed)
     nums = compare.numbers(readings, ref)

@@ -20,12 +20,19 @@ program:
   ``T^2 KL(teacher_T || student_T)`` weighted by the teacher's validity,
   and test accuracy.
 
+Client ``cid`` lives on ``devices[cid % len(devices)]``: its parameters,
+momentum, private data and filter are committed there and its calls run
+there, each update in place of the state it replaces (the state is
+donated). The teacher is formed on ``devices[0]``. So a cohort whose
+state no one chip holds is followed on the chips that the cell asks for.
+
 All of it runs in ``dtype`` with matmuls and convolutions at ``precision``:
 float32 at ``highest`` is the reference; bfloat16 is the control.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+import functools
+from typing import Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -155,7 +162,7 @@ def _make_fns(apply, lr: float, temperature: float, dtype,
     def rows(xb):
         return xb[: xb.shape[0] // 2] if fault == "half_batch" else xb
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
     def train(params, mu, x, y, idx):
         def loss_fn(p, ib):
             xb, yb = rows(jnp.take(x, ib, axis=0)), rows(jnp.take(y, ib,
@@ -164,7 +171,7 @@ def _make_fns(apply, lr: float, temperature: float, dtype,
             return -jnp.mean(jnp.take_along_axis(logp, yb[:, None], 1))
         return sgd_scan(loss_fn, params, mu, (idx,))
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
     def distill(params, mu, px, teacher, w, idx):
         t = temperature
 
@@ -213,13 +220,22 @@ def _norms(leaves) -> np.ndarray:
         np.float64)
 
 
-def run(config: dict, traffic: dict, data, seed: int, *, rounds: int = 3,
+def _on_each(x, devices) -> List[jax.Array]:
+    """``x`` committed to each of ``devices``, in their order."""
+    return [jax.device_put(x, d) for d in devices]
+
+
+def run(config: dict, traffic: dict, data, seed: int, *,
+        devices: Optional[Sequence] = None, rounds: int = 3,
         dtype=jnp.float32, precision="highest",
         fault: Optional[str] = None) -> Dict:
-    """Follow the first ``rounds`` rounds; return the readings the
-    comparison reads (see ``compare.readings_keys``)."""
+    """Follow the first ``rounds`` rounds on ``devices`` (the first device
+    alone by default); return the readings the comparison reads (see
+    ``compare.readings_keys``)."""
     if fault is not None and fault not in FAULTS:
         raise ValueError(f"unknown fault {fault!r}")
+    devices = list(devices or jax.devices()[:1])
+    n_dev = len(devices)
     k_cls = config["num_classes"]
     n_cl = traffic["num_clients"]
     batch = traffic["batch_size"]
@@ -234,31 +250,38 @@ def run(config: dict, traffic: dict, data, seed: int, *, rounds: int = 3,
     fn_cache: Dict = {}
     for cid, (x, y) in enumerate(clients):
         key, sub = jax.random.split(key)
-        p = jax.tree.map(lambda a: a.astype(dtype),
-                         model.init_params(sub, config, cid))
-        params.append(p)
-        mus.append(jax.tree.map(jnp.zeros_like, p))
+        dev = devices[cid % n_dev]
+        with jax.default_device(dev):       # made there, not moved there
+            p = jax.tree.map(lambda a: a.astype(dtype),
+                             model.init_params(sub, config, cid))
+            params.append(jax.device_put(p, dev))
+            mus.append(jax.device_put(jax.tree.map(jnp.zeros_like, p), dev))
+            xs.append(jax.device_put(_inputs(x, dtype), dev))
+            ys.append(jax.device_put(jnp.asarray(y), dev))
         arch = model.arch_key(config, cid)
         if arch not in fn_cache:
             fn_cache[arch] = _make_fns(
                 model.make_apply(config, cid, precision), traffic["lr"],
                 traffic["temperature"], dtype, fault)
         fns.append(fn_cache[arch])
-        xs.append(_inputs(x, dtype))
-        ys.append(jnp.asarray(y))
         rngs.append(np.random.default_rng(seed + 1000 * cid))
     dre_key = jax.random.PRNGKey(seed)
     for cid, (x, _) in enumerate(clients):
-        cents, thr = _fit_filter_jit(jax.random.fold_in(dre_key, cid),
-                                     jnp.asarray(x), n_centroids)
-        if fault == "threshold_altered":
-            thr = thr * THRESHOLD_FAULT
-        filters.append((cents, thr))
+        dev = devices[cid % n_dev]
+        with jax.default_device(dev):
+            cents, thr = _fit_filter_jit(jax.random.fold_in(dre_key, cid),
+                                         jnp.asarray(x), n_centroids)
+            if fault == "threshold_altered":
+                thr = thr * THRESHOLD_FAULT
+            filters.append(jax.device_put((cents, thr), dev))
     server_rng = np.random.default_rng(seed + 7)
-    p0 = [_leaves(p) for p in params]
+    # the starting point, copied to the host: the device's is donated
+    p0 = [[np.array(a, copy=True) for a in _leaves(p)] for p in params]
     x_test = np.asarray(data.x_test)
-    y_test = jnp.asarray(data.y_test)
     n_test = len(data.y_test)
+    tests = _on_each([(_inputs(x_test[s:s + EVAL_BATCH], dtype),
+                       jnp.asarray(data.y_test[s:s + EVAL_BATCH]))
+                      for s in range(0, n_test, EVAL_BATCH)], devices)
 
     out = {"local_loss": [], "distill_loss": [], "accs": [], "id_fracs": []}
     for r in range(rounds):
@@ -275,18 +298,23 @@ def run(config: dict, traffic: dict, data, seed: int, *, rounds: int = 3,
         sel = server_rng.choice(len(powner_all),
                                 size=min(traffic["proxy_batch"],
                                          len(powner_all)), replace=False)
-        px = _inputs(px_all[sel], dtype)
-        powner = jnp.asarray(powner_all[sel])
-        px_flat = jnp.asarray(px_all[sel].reshape(len(sel), -1))
+        px = _on_each(_inputs(px_all[sel], dtype), devices)
+        powner = _on_each(jnp.asarray(powner_all[sel]), devices)
+        px_flat = _on_each(jnp.asarray(px_all[sel].reshape(len(sel), -1)),
+                           devices)
         lg, mk = [], []
         for cid in range(n_cl):
-            lg.append(fns[cid][2](params[cid], px))
-            mk.append(_filter_mask(*filters[cid], px_flat, powner, cid))
-        lg, mk = jnp.stack(lg), jnp.stack(mk)
+            d = cid % n_dev
+            lg.append(fns[cid][2](params[cid], px[d]))
+            mk.append(_filter_mask(*filters[cid], px_flat[d], powner[d],
+                                   cid))
+        lg = jnp.stack(jax.device_put(lg, devices[0]))
+        mk = jnp.stack(jax.device_put(mk, devices[0]))
         m = mk.astype(jnp.float32)[..., None]
         cnt = jnp.sum(m, axis=0)
-        teacher = jnp.sum(lg * m, axis=0) / jnp.maximum(cnt, 1.0)
-        w = (cnt[..., 0] > 0).astype(jnp.float32)
+        teacher = _on_each(jnp.sum(lg * m, axis=0) / jnp.maximum(cnt, 1.0),
+                           devices)
+        w = _on_each((cnt[..., 0] > 0).astype(jnp.float32), devices)
         dist = []
         t = len(sel)
         for cid in range(n_cl):
@@ -294,16 +322,16 @@ def run(config: dict, traffic: dict, data, seed: int, *, rounds: int = 3,
             perm = rngs[cid].permutation(t)
             idx = perm[: nb * batch].reshape(nb, batch) if t >= batch \
                 else perm[None]
+            d = cid % n_dev
             params[cid], mus[cid], loss = fns[cid][1](
-                params[cid], mus[cid], px, teacher, w, jnp.asarray(idx))
+                params[cid], mus[cid], px[d], teacher[d], w[d],
+                jnp.asarray(idx))
             dist.append(loss)
         accs = []
         for cid in range(n_cl):
             c = 0
-            for s in range(0, n_test, EVAL_BATCH):
-                c += fns[cid][3](params[cid],
-                                 _inputs(x_test[s:s + EVAL_BATCH], dtype),
-                                 y_test[s:s + EVAL_BATCH])
+            for xb, yb in tests[cid % n_dev]:
+                c += fns[cid][3](params[cid], xb, yb)
             accs.append(c)
         local, dist, accs, fr = jax.device_get(
             (local, dist, accs, jnp.mean(mk.astype(jnp.float32), axis=1)))

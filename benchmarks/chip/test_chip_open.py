@@ -276,7 +276,7 @@ def test_leaves_in_layer_order_with_keys_sorted():
     assert reference._leaves(stacked) == order
     engine = SimpleNamespace(cohorts=[SimpleNamespace(
         positions=[7, 3], params=stacked)])
-    got = harness.member_leaves(engine, lambda c: c.params)
+    got = dict(harness.member_leaves(engine, lambda c: c.params))
     assert sorted(got) == [3, 7]
     for j, cid in enumerate([7, 3]):
         assert [a.tolist() for a in got[cid]] \
