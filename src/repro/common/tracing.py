@@ -13,7 +13,7 @@ one round share an identifier also under ``round_mode="overlap"``.
 ``server.ingest``      the server's report ingest, after the report node
 ``server.aggregate``   the server's reduce, byte accounting, outliers
 ``server.fetch``       a device->host read in the server
-``cohort.plan``        the cohort's per-client permutation plans
+``cohort.plan``        the cohort's batch plans, packed by client size
 ``cohort.stage``       host->device staging
 ``cohort.launch``      one call of a jitted cohort program
 ``cohort.fetch``       a device->host read in the cohort engine
@@ -23,11 +23,12 @@ Only ``span`` (``phase.<name>``, ``server.ingest``) reads the clock with
 the profiler off: ``RoundLog.phase_s`` is its duration.
 
 Counters are integer adds, always on: ``engine.syncs`` (one per
-``cohort.fetch``), ``server.syncs`` (one per ``server.fetch``) and
-``compiles`` (programs JAX built, from the compile event the benchmark
-counts too; ``compiles()`` also sums their seconds). They are process
-totals, counted from the first call of ``counts`` or ``compiles``;
-the scheduler books each node's increments on its round
+``cohort.fetch``), ``server.syncs`` (one per ``server.fetch``),
+``engine.plan_groups`` (one per client-size group ``cohort.plan``
+packs) and ``compiles`` (programs JAX built, from the compile event
+the benchmark counts too; ``compiles()`` also sums their seconds). They
+are process totals, counted from the first call of ``counts`` or
+``compiles``; the scheduler books each node's increments on its round
 (``RoundLog.counters``) with ``counts`` and ``book``.
 """
 from __future__ import annotations
@@ -41,7 +42,8 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-COUNTERS = ("engine.syncs", "server.syncs", "compiles")
+COUNTERS = ("engine.syncs", "server.syncs", "engine.plan_groups",
+            "compiles")
 _FETCH = {"engine": ("cohort.fetch", "engine.syncs"),
           "server": ("server.fetch", "server.syncs")}
 
@@ -73,6 +75,11 @@ def book(into: Dict[str, int], mark: Tuple[int, ...]) -> None:
     """Add what each counter counted since ``mark`` to ``into``."""
     for k, m in zip(COUNTERS, mark):
         into[k] = into.get(k, 0) + _totals[k] - m
+
+
+def add(counter: str, n: int) -> None:
+    """Count ``n`` more of ``counter``."""
+    _totals[counter] += n
 
 
 def compiles() -> Tuple[int, float]:
@@ -143,7 +150,7 @@ def fetch(x, side: str = "engine"):
     (``side="server"``), counted as one sync. A tree's copies all start
     before the first is waited for."""
     name, counter = _FETCH[side]
-    _totals[counter] += 1
+    add(counter, 1)
     with TraceAnnotation(name, **_meta):
         if isinstance(x, jax.Array):
             return np.asarray(x)

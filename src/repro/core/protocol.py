@@ -67,8 +67,9 @@ class RoundLog:
     phase_s: Dict[str, float] = dataclasses.field(default_factory=dict)
     # what the round's phase nodes did beyond their time
     # (repro.common.tracing): device->host reads of the engine
-    # ("engine.syncs") and of the server ("server.syncs"), and programs
-    # JAX built ("compiles")
+    # ("engine.syncs") and of the server ("server.syncs"), the client-size
+    # groups the cohort engine's plans packed ("engine.plan_groups"), and
+    # programs JAX built ("compiles")
     counters: Dict[str, int] = dataclasses.field(default_factory=dict)
     # when this round retired on the simulated straggler timeline
     # (repro.fed.clock) — the axis on which round_mode="overlap" beats

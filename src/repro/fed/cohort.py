@@ -717,6 +717,14 @@ class _Cohort:
         all-False step validity — the same ``_where_tree`` no-op gating
         that freezes dummy padding clients. The plan arrays keep their
         shapes either way, so a changing subset never retraces a phase.
+
+        Drawing members are packed by size: members of one size ``n`` are
+        shuffled in place into one ``(G, epochs, n)`` buffer
+        (``rng.shuffle`` of ``arange(n)`` is the stream ``permutation(n)``
+        draws), their ``idx`` rows are cut from it in one reshape, and
+        ``w``/``valid`` — which depend on ``n`` alone — come from one
+        ``padded_epoch_plan`` call per size. Each group counts one
+        ``engine.plan_groups``.
         """
         C = len(self.members)
         if draw_n >= 0:
@@ -732,11 +740,26 @@ class _Cohort:
         idx = np.zeros((lead, steps, batch_size), np.int32)
         w = np.zeros((lead, steps, batch_size), np.float32)
         valid = np.zeros((lead, steps), bool)
-        for i, c in enumerate(self.members):
-            if part is not None and not part[i]:
-                continue               # no-op lane this round
-            perms = [c.rng.permutation(ns[i]) for _ in range(epochs)]
-            idx[i], w[i], valid[i] = padded_epoch_plan(perms, batch_size, steps)
+        groups: Dict[int, List[int]] = {}
+        for i in range(C):
+            if part is None or part[i]:  # sampled-out members draw nothing
+                groups.setdefault(ns[i], []).append(i)
+        for n, rows in groups.items():
+            # intp rows: numpy shuffles pointer-sized items fastest
+            buf = np.empty((len(rows), epochs, n), np.intp)
+            buf[...] = np.arange(n)
+            for j, i in enumerate(rows):
+                rng = self.members[i].rng
+                for e in range(epochs):
+                    rng.shuffle(buf[j, e])
+            s = steps_per_epoch(n, batch_size)
+            width = min(n, batch_size)
+            idx[rows, : epochs * s, :width] = buf[:, :, : s * width].reshape(
+                len(rows), epochs * s, width)
+            # w/valid depend on n alone: one member's plan stands for all
+            _, w[rows], valid[rows] = padded_epoch_plan(
+                list(buf[0]), batch_size, steps)
+        tracing.add("engine.plan_groups", len(groups))
         if weight is not None:
             w = w * np.asarray(weight, np.float32)[idx]
         return idx, w, valid

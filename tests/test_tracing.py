@@ -138,6 +138,39 @@ def test_counters_ride_the_round_state():
     assert again.logs[-1].counters["engine.syncs"] == EDGEFD_ENGINE_SYNCS
 
 
+def _plan_groups(sched):
+    """Size groups of one edgefd round: the private sizes of each cohort's
+    ``local_train`` plan, and one shared proxy size for ``distill``."""
+    return sum(len(set(c.n.tolist())) + 1 for c in sched.engine.cohorts)
+
+
+def test_plan_groups_count_the_distinct_sizes():
+    sched = _sched()
+    assert len(sched.engine.cohorts) == 1
+    for r in range(2):
+        assert _round(sched, r).counters["engine.plan_groups"] \
+            == _plan_groups(sched)
+
+
+def test_plan_groups_ride_the_round_state():
+    sched = _sched()
+    sched.begin(0, 1)
+    sched.step()                                  # local_train
+    tree = sched.snapshot().to_tree()
+    private = _plan_groups(sched) - 1
+    assert tree["scheduler"]["states"][0]["counters"][
+        "engine.plan_groups"] == private
+    again = _sched()
+    again.restore(tree)
+    again.drain()
+    assert again.logs[-1].counters["engine.plan_groups"] == private + 1
+
+
+def test_loop_engine_books_no_plan_groups():
+    assert _round(_sched(engine="loop"), 0).counters[
+        "engine.plan_groups"] == 0
+
+
 def test_loop_engine_books_server_syncs_only():
     lg = _round(_sched(engine="loop"), 0)
     assert lg.counters["engine.syncs"] == 0
