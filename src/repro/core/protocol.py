@@ -22,11 +22,12 @@ the same graph drives two execution strategies:
     into leading-axis pytrees and run every per-client op under ``vmap``
     (one compiled call per round phase for the whole cohort).
 
-Engines expose one entry point per phase (``phase_local_train``,
-``phase_report``, ``phase_classwise_report``, ``phase_distill``,
-``phase_distill_private``, ``phase_eval``); the historical ``*_all``
-mega-call names remain as thin aliases for existing callers. Both engines
-produce identical ``RoundLog`` streams for the same seed (see
+Both engines expose one contract: ``cohort_positions()`` and one call per
+client phase and cohort (``cohort_local_train``, ``cohort_report``,
+``cohort_classwise_report``, ``cohort_distill``,
+``cohort_distill_private``), one fleet-wide ``evaluate``, ``learn_dres``
+and the ``state_dict``/``load_state_dict``/``sync_to_clients`` service
+hooks. Both produce identical ``RoundLog`` streams for the same seed (see
 ``tests/test_cohort_parity.py``); ``FedConfig.engine`` selects one.
 """
 from __future__ import annotations
@@ -129,13 +130,22 @@ class LoopEngine:
     skipping local training — see ``repro.fed.batching``); ``CohortEngine``
     must match its outputs up to float tolerance.
 
-    The ``phase_*`` methods are the scheduler's per-phase entry points; the
-    ``*_all`` mega-call names below are thin aliases kept for historical
-    callers.
+    It groups clients into cohorts by ``arch_key`` exactly like
+    ``CohortEngine``, so loop == cohort round-log parity holds node for
+    node; every ``cohort_*`` call returns values aligned to that cohort's
+    client positions and the scheduler scatters them back into
+    fleet-length structures.
     """
 
     def __init__(self, clients: Sequence["Client"]):
         self.clients = list(clients)
+        # client positions per cohort, grouped by ``arch_key`` in first-
+        # appearance order (clients without an arch_key are singletons)
+        groups: Dict = {}
+        for pos, c in enumerate(self.clients):
+            key = c.arch_key if c.arch_key is not None else ("solo", pos)
+            groups.setdefault(key, []).append(pos)
+        self._cohort_pos = [np.asarray(p, int) for p in groups.values()]
 
     @property
     def num_clients(self) -> int:
@@ -161,80 +171,8 @@ class LoopEngine:
         for i, c in enumerate(self.clients):
             c.learn_dre(jax.random.fold_in(key, i))
 
-    # ------------------------------------------------ per-phase entry points
-    def phase_local_train(self, epochs: int, batch_size: int,
-                          participants=None) -> List[float]:
-        part = self._part(participants)
-        return [c.local_train(epochs, batch_size) if part[i] else 0.0
-                for i, c in enumerate(self.clients)]
-
-    def phase_classwise_report(self, participants=None):
-        part = self._part(participants)
-        k = self.clients[0].num_classes
-        # zero counts: a sampled-out client contributes nothing classwise
-        skipped = (np.zeros((k, k), np.float32), np.zeros((k,), np.float32))
-        return [c.classwise_means() if part[i] else skipped
-                for i, c in enumerate(self.clients)]
-
-    def phase_report(self, px, powner, participants=None):
-        """Returns (logits (C, t, K), masks (C, t)) as numpy arrays;
-        sampled-out clients get zero logits and all-False masks (the
-        staleness buffer replaces those rows with their last report)."""
-        part = self._part(participants)
-        t = len(px)
-        k = self.clients[0].num_classes
-        logits = np.zeros((len(self.clients), t, k), np.float32)
-        masks = np.zeros((len(self.clients), t), bool)
-        for i, c in enumerate(self.clients):               # lines 20–25
-            if not part[i]:
-                continue
-            logits[i] = np.asarray(c.proxy_logits(px))
-            masks[i] = np.asarray(c.filter_mask(px, powner).mask)
-        return logits, masks
-
-    def phase_distill(self, px, teacher, weight, epochs: int,
-                      batch_size: int, participants=None) -> List[float]:
-        part = self._part(participants)
-        return [c.distill(px, teacher, weight, epochs, batch_size)
-                if part[i] else 0.0
-                for i, c in enumerate(self.clients)]
-
-    def phase_distill_private(self, teacher_by_class, valid_by_class,
-                              epochs: int, batch_size: int,
-                              participants=None) -> List[float]:
-        part = self._part(participants)
-        out = []
-        for i, c in enumerate(self.clients):
-            if not part[i]:
-                out.append(0.0)
-                continue
-            teacher = teacher_by_class[c.y]                # (n, K)
-            w = valid_by_class[c.y].astype(np.float32)
-            out.append(c.distill(c.x, teacher, w, epochs, batch_size))
-        return out
-
-    def phase_eval(self, x_test, y_test) -> List[float]:
-        return [c.evaluate(x_test, y_test) for c in self.clients]
-
     # ------------------------------------------------ per-cohort entry points
-    # Concurrent-cohort scheduling (repro.fed.scheduler with
-    # cfg.concurrent_cohorts=True) keys client-side phase nodes per cohort
-    # and drives each group independently. The loop engine groups clients
-    # by arch_key exactly like CohortEngine so loop == cohort round-log
-    # parity holds node-for-node; every cohort_* call returns values
-    # aligned to that cohort's client positions and the scheduler scatters
-    # them back into fleet-length structures.
-
     def cohort_positions(self) -> List[np.ndarray]:
-        """Client positions per cohort, grouped by ``arch_key`` in first-
-        appearance order (clients without an arch_key are singletons) —
-        the same grouping rule as ``CohortEngine``."""
-        if getattr(self, "_cohort_pos", None) is None:
-            groups: Dict = {}
-            for pos, c in enumerate(self.clients):
-                key = c.arch_key if c.arch_key is not None else ("solo", pos)
-                groups.setdefault(key, []).append(pos)
-            self._cohort_pos = [np.asarray(p, int) for p in groups.values()]
         return self._cohort_pos
 
     def cohort_local_train(self, ci: int, epochs: int, batch_size: int,
@@ -253,7 +191,9 @@ class LoopEngine:
 
     def cohort_report(self, ci: int, px, powner, participants=None):
         """Returns (logits (m, t, K), masks (m, t)) for cohort ``ci``'s m
-        clients; sampled-out rows stay zero/False like ``phase_report``."""
+        clients; sampled-out clients get zero logits and all-False masks
+        (the staleness buffer replaces those rows with their last
+        report)."""
         part = self._part(participants)
         pos = self.cohort_positions()[ci]
         t = len(px)
@@ -291,6 +231,9 @@ class LoopEngine:
             out.append(c.distill(c.x, teacher, w, epochs, batch_size))
         return out
 
+    def evaluate(self, x_test, y_test) -> List[float]:
+        return [c.evaluate(x_test, y_test) for c in self.clients]
+
     # ------------------------------------------------- resumable service
     def state_dict(self) -> Dict:
         """Per-client mutable state (params, opt-state, rng) in the shared
@@ -303,30 +246,8 @@ class LoopEngine:
         from repro.fed.state import load_clients_state_dict
         load_clients_state_dict(self.clients, sd)
 
-    # -------------------------- historical mega-call names (thin aliases)
-    def local_train_all(self, epochs: int, batch_size: int,
-                        participants=None) -> List[float]:
-        return self.phase_local_train(epochs, batch_size, participants)
-
-    def classwise_means_all(self, participants=None):
-        return self.phase_classwise_report(participants)
-
-    def proxy_logits_and_masks(self, px, powner, participants=None):
-        return self.phase_report(px, powner, participants)
-
-    def distill_all(self, px, teacher, weight, epochs: int,
-                    batch_size: int, participants=None) -> List[float]:
-        return self.phase_distill(px, teacher, weight, epochs, batch_size,
-                                  participants)
-
-    def distill_private_all(self, teacher_by_class, valid_by_class,
-                            epochs: int, batch_size: int,
-                            participants=None) -> List[float]:
-        return self.phase_distill_private(teacher_by_class, valid_by_class,
-                                          epochs, batch_size, participants)
-
-    def evaluate_all(self, x_test, y_test) -> List[float]:
-        return self.phase_eval(x_test, y_test)
+    def sync_to_clients(self) -> None:
+        """No-op: the loop engine trains the ``Client`` objects in place."""
 
 
 def as_engine(clients_or_engine, engine: str = "loop", *,
@@ -342,7 +263,7 @@ def as_engine(clients_or_engine, engine: str = "loop", *,
     ``wave_size`` streams the cohort client axis through the device in
     fixed-size waves (``repro.fed.cohort``); 0 = whole axis resident.
     """
-    if hasattr(clients_or_engine, "local_train_all"):
+    if hasattr(clients_or_engine, "cohort_positions"):
         if wave_size and not getattr(clients_or_engine, "wave_size", 0):
             warnings.warn(
                 f"wave_size={wave_size} requested but a pre-built engine "
@@ -390,7 +311,7 @@ def engine_from_config(clients_or_engine, cfg: FedConfig):
     return as_engine(clients_or_engine, cfg.engine,
                      num_devices=cfg.num_devices, mesh_axis=cfg.mesh_axis,
                      wave_size=cfg.wave_size,
-                     model_shards=getattr(cfg, "model_shards", 0))
+                     model_shards=cfg.model_shards)
 
 
 # ---------------------------------------------------------------------------
@@ -426,7 +347,7 @@ def run_round(r: int, clients, server: "Server", method: Method,
     transient = engine is not clients
     log = _scheduler(engine, server, method, cfg, x_test, y_test
                      ).run_rounds(r, 1)[0]
-    if transient and hasattr(engine, "sync_to_clients"):
+    if transient:
         # engines that train on stacked device state (CohortEngine) must
         # write params/opt-state back before being discarded, or raw-list
         # callers would silently lose every round's training
@@ -445,7 +366,7 @@ def run_experiment(clients, server: "Server", method_name: str,
         engine.learn_dres(key)
     logs = _scheduler(engine, server, method, cfg, x_test, y_test
                       ).run_rounds(0, cfg.rounds, progress=progress)
-    if engine is not clients and hasattr(engine, "sync_to_clients"):
+    if engine is not clients:
         # raw-list callers hold only the Client objects — an engine built
         # here must write its trained stacked state back before vanishing
         engine.sync_to_clients()

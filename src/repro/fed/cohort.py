@@ -1052,7 +1052,7 @@ class _Cohort:
 
 
 class CohortEngine:
-    """Engine over architecture-grouped cohorts; same interface as LoopEngine.
+    """Engine over architecture-grouped cohorts; same contract as LoopEngine.
 
     The ``Client`` objects remain the source of private data, DRE config and
     rng streams, but their params/opt-state live *stacked on device* for the
@@ -1113,58 +1113,11 @@ class CohortEngine:
         for cohort in self.cohorts:
             cohort.learn_dres(key)
 
-    # ------------------------------------------------ per-phase entry points
-    # (driven by repro.fed.scheduler; the *_all mega-call names below are
-    # thin aliases kept for historical callers)
-    def phase_local_train(self, epochs: int, batch_size: int,
-                          participants=None) -> List[float]:
-        return self._scatter(
-            [c.local_train(epochs, batch_size,
-                           part=self._part_for(c, participants))
-             for c in self.cohorts])
-
-    def phase_classwise_report(self, participants=None):
-        return self._scatter(
-            [c.classwise_means(part=self._part_for(c, participants))
-             for c in self.cohorts])
-
-    def phase_report(self, px, powner, participants=None):
-        t = len(px)
-        k = self.clients[0].num_classes
-        logits = np.zeros((len(self.clients), t, k), np.float32)
-        masks = np.zeros((len(self.clients), t), bool)
-        for cohort in self.cohorts:
-            part = self._part_for(cohort, participants)
-            logits[cohort.positions] = cohort.proxy_logits(px, part=part)
-            masks[cohort.positions] = cohort.filter_masks(px, powner,
-                                                          part=part)
-        return logits, masks
-
-    def phase_distill(self, px, teacher, weight, epochs: int,
-                      batch_size: int, participants=None) -> List[float]:
-        return self._scatter(
-            [c.distill(px, teacher, weight, epochs, batch_size,
-                       part=self._part_for(c, participants))
-             for c in self.cohorts])
-
-    def phase_distill_private(self, teacher_by_class, valid_by_class,
-                              epochs: int, batch_size: int,
-                              participants=None) -> List[float]:
-        return self._scatter(
-            [c.distill_private(teacher_by_class, valid_by_class, epochs,
-                               batch_size,
-                               part=self._part_for(c, participants))
-             for c in self.cohorts])
-
-    def phase_eval(self, x_test, y_test) -> List[float]:
-        return self._scatter([c.evaluate(x_test, y_test)
-                              for c in self.cohorts])
-
     # ------------------------------------------------ per-cohort entry points
-    # Concurrent-cohort scheduling (repro.fed.scheduler with
-    # cfg.concurrent_cohorts=True) drives each _Cohort independently so
-    # different cohorts' phases interleave on the round graph. Each call
-    # returns values aligned to that cohort's client positions
+    # The scheduler drives every client phase one _Cohort at a time: a
+    # serial phase node walks every cohort in order, a concurrent-cohort
+    # node (cfg.concurrent_cohorts=True) covers one. Each call returns
+    # values aligned to that cohort's client positions
     # (``cohort_positions()[ci]``); the scheduler scatters them back into
     # fleet-length structures. LoopEngine implements the same interface
     # with the same grouping rule, so loop == cohort parity holds
@@ -1205,30 +1158,11 @@ class CohortEngine:
                                  batch_size,
                                  part=self._part_for(c, participants))
 
-    # -------------------------- historical mega-call names (thin aliases)
-    def local_train_all(self, epochs: int, batch_size: int,
-                        participants=None) -> List[float]:
-        return self.phase_local_train(epochs, batch_size, participants)
-
-    def classwise_means_all(self, participants=None):
-        return self.phase_classwise_report(participants)
-
-    def proxy_logits_and_masks(self, px, powner, participants=None):
-        return self.phase_report(px, powner, participants)
-
-    def distill_all(self, px, teacher, weight, epochs: int,
-                    batch_size: int, participants=None) -> List[float]:
-        return self.phase_distill(px, teacher, weight, epochs, batch_size,
-                                  participants)
-
-    def distill_private_all(self, teacher_by_class, valid_by_class,
-                            epochs: int, batch_size: int,
-                            participants=None) -> List[float]:
-        return self.phase_distill_private(teacher_by_class, valid_by_class,
-                                          epochs, batch_size, participants)
-
-    def evaluate_all(self, x_test, y_test) -> List[float]:
-        return self.phase_eval(x_test, y_test)
+    def evaluate(self, x_test, y_test) -> List[float]:
+        """Fleet-wide accuracies in client order (the eval node is never
+        per cohort)."""
+        return self._scatter([c.evaluate(x_test, y_test)
+                              for c in self.cohorts])
 
     def sync_to_clients(self) -> None:
         for cohort in self.cohorts:

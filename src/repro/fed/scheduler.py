@@ -65,6 +65,9 @@ bit-for-bit the serial schedule when only one cohort exists). Aggregation
 stays a global barrier — the protocol needs every cohort's report — so the
 win is cross-round desynchronization, measurable with per-cohort phase
 costs (``sim_phase_costs["phase@cohort"]``, ``benchmarks/hetero_zoo.py``).
+Both graphs run the same node bodies: a client-phase body loops over the
+cohorts its node covers (every cohort for a serial node, one for a
+concurrent one) and calls the engine's per-cohort entry point for each.
 
 **FedDF ensemble server** (``method="server_distill"``): a ``server_distill``
 phase node between ``aggregate`` and ``distill`` trains the server's central
@@ -80,6 +83,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.common import tracing
+from repro.common.types import FedConfig
 from repro.core.protocol import RoundLog
 from repro.fed.clock import (ARRIVAL_PROCESSES, SimTimeline, arrival_offsets,
                              client_speeds, dropout_mask, online_mask)
@@ -116,8 +120,8 @@ def resolve_round_mode(mode: Optional[str]) -> str:
     return mode
 
 
-def validate_config(cfg) -> None:
-    """Fail fast on an inconsistent scheduler config (FedConfig-like)."""
+def validate_config(cfg: FedConfig) -> None:
+    """Fail fast on an inconsistent scheduler config."""
     resolve_round_mode(cfg.round_mode)
     if cfg.max_inflight < 1:
         raise ValueError(
@@ -141,30 +145,28 @@ def validate_config(cfg) -> None:
     if cfg.arrival_bursts < 1:
         raise ValueError(
             f"arrival_bursts must be >= 1, got {cfg.arrival_bursts!r}")
-    for knob in ("churn_prob", "dropout_prob"):
-        v = getattr(cfg, knob)
+    for knob, v in (("churn_prob", cfg.churn_prob),
+                    ("dropout_prob", cfg.dropout_prob)):
         if not 0.0 <= v < 1.0:
             raise ValueError(f"{knob} must be in [0, 1), got {v!r}")
-    if getattr(cfg, "max_pending_reports", 0) < 0:
+    if cfg.max_pending_reports < 0:
         raise ValueError(
             f"max_pending_reports must be >= 0 (0 = unbounded), got "
             f"{cfg.max_pending_reports!r}")
-    validate_fault_config(getattr(cfg, "fault_mode", "none"),
-                          getattr(cfg, "fault_prob", 0.0),
-                          getattr(cfg, "byzantine_frac", 0.0),
-                          getattr(cfg, "fault_start", 0),
-                          getattr(cfg, "fault_duration", 0))
+    validate_fault_config(cfg.fault_mode, cfg.fault_prob,
+                          cfg.byzantine_frac, cfg.fault_start,
+                          cfg.fault_duration)
     # robust_aggregation / trust knobs are validated where they land (the
     # Server constructor); the watchdog knobs live here with the scheduler
-    if getattr(cfg, "watchdog_max_rollbacks", 3) < 0:
+    if cfg.watchdog_max_rollbacks < 0:
         raise ValueError(
             f"watchdog_max_rollbacks must be >= 0, got "
             f"{cfg.watchdog_max_rollbacks!r}")
-    if getattr(cfg, "watchdog_acc_drop", 0.2) <= 0.0:
+    if cfg.watchdog_acc_drop <= 0.0:
         raise ValueError(
             f"watchdog_acc_drop must be > 0, got "
             f"{cfg.watchdog_acc_drop!r}")
-    if getattr(cfg, "watchdog_loss_factor", 10.0) <= 1.0:
+    if cfg.watchdog_loss_factor <= 1.0:
         raise ValueError(
             f"watchdog_loss_factor must be > 1, got "
             f"{cfg.watchdog_loss_factor!r}")
@@ -174,20 +176,12 @@ def round_phases(method) -> Tuple[str, ...]:
     """The phase nodes one round of ``method`` contributes to the graph."""
     if method.name == "indlearn":  # no collaboration: train, then measure
         return ("local_train", "eval")
-    if getattr(method, "server_distill", False):
+    if method.server_distill:
         # FedDF: the server student trains on the fused teacher before the
         # clients distill — a serial server-lane node riding the graph
         return ("local_train", "report", "aggregate", "server_distill",
                 "distill", "eval")
     return PHASE_ORDER
-
-
-def _entry(engine, phase_name: str, legacy_name: str) -> Callable:
-    """Resolve an engine phase entry point, preferring the per-phase
-    interface and falling back to the historical ``*_all`` mega-call (so
-    pre-built duck-typed engines keep working unchanged)."""
-    fn = getattr(engine, phase_name, None)
-    return fn if fn is not None else getattr(engine, legacy_name)
 
 
 def _node_meta(key: Tuple) -> Dict[str, int]:
@@ -200,20 +194,17 @@ def _node_meta(key: Tuple) -> Dict[str, int]:
 class _RoundState:
     """Mutable state threaded between one round's phase nodes."""
 
-    __slots__ = ("r", "part", "kw", "idx", "px", "powner", "means_counts",
+    __slots__ = ("r", "part", "idx", "px", "powner", "means_counts",
                  "teacher", "valid", "teacher_by_class", "valid_by_class",
                  "local_losses", "distill_losses", "id_frac", "id_fracs",
                  "mean_staleness", "accs", "phase_s", "counters",
-                 "sim_finish_s",
-                 "report_payload", "rpart", "sampled", "reports_pending",
+                 "sim_finish_s", "rpart", "sampled", "reports_pending",
                  "report_logits", "report_masks", "report_arrival",
                  "server_distill_loss", "server_student_acc")
 
     def __init__(self, r: int):
         self.r = r
-        self.part = None            # participation mask (None = everyone)
-        self.kw: Dict = {}          # engine kwargs ({} keeps the legacy
-        #                             call sequence at fraction 1)
+        self.part = None            # training mask (None = everyone)
         self.idx = None             # proxy indices / batch / owners
         self.px = None
         self.powner = None
@@ -231,21 +222,17 @@ class _RoundState:
         self.phase_s: Dict[str, float] = {}
         self.counters: Dict[str, int] = {}
         self.sim_finish_s = 0.0
-        # (logits, masks) parked between the report body and the
-        # post-pricing ingest event; consumed within the same node
-        # execution, so never present at a phase boundary
-        self.report_payload = None
-        # --- concurrent-cohort bookkeeping (serial mode leaves these at
-        # their defaults). The round's *reporting* participants: training
-        # participation (st.part) minus mid-round dropout and admission
-        # overflow — serial mode mutates st.part in place instead, but with
-        # per-cohort nodes a later cohort's local_train may still need the
-        # pre-dropout mask. None = same as st.part.
+        # The round's *reporting* participants: training participation
+        # (st.part) minus mid-round dropout and admission overflow. Kept
+        # apart from st.part because, with per-cohort nodes, a later
+        # cohort's local_train may still need the pre-dropout mask.
+        # None = same as st.part.
         self.rpart = None
         self.sampled = False        # participation drawn for this round?
-        # per-cohort report accumulation: cohort report nodes fill their
-        # rows here; ingestion fires at the round's last report node. These
-        # DO live across phase boundaries, so they are checkpointed.
+        # report accumulation: report nodes fill their cohorts' rows here
+        # and count down reports_pending; ingestion fires at zero. With
+        # per-cohort nodes these live across phase boundaries, so they are
+        # checkpointed.
         self.reports_pending = None
         self.report_logits = None
         self.report_masks = None
@@ -260,9 +247,8 @@ class _RoundState:
     def state_dict(self) -> Dict:
         """Mutable payload of a partially-executed (in-flight) round.
 
-        ``px``/``powner``/``kw`` are derived fields (recomputed from
-        ``idx``/``part`` on restore) and ``report_payload`` is transient
-        within one node execution, so none of them is captured. Losses and
+        ``px``/``powner`` are derived fields (recomputed from ``idx`` on
+        restore), so they are not captured. Losses and
         accuracies are plain python floats end-to-end, which the JSON
         manifest round-trips exactly (``repr`` round-trip)."""
         from repro.fed.state import opt_array
@@ -302,7 +288,6 @@ class _RoundState:
     def load_state_dict(self, sd: Dict, scheduler) -> None:
         from repro.fed.state import opt_array
         self.part = opt_array(sd["part"], bool)
-        self.kw = {} if self.part is None else {"participants": self.part}
         self.idx = opt_array(sd["idx"])
         if self.idx is not None:
             self.px = scheduler.server.proxy.x[self.idx]
@@ -327,8 +312,9 @@ class _RoundState:
                          for k, v in sd.get("counters", {}).items()}
         self.sim_finish_s = float(sd["sim_finish_s"])
         # concurrent-cohort / ensemble-server fields (``.get``: absent from
-        # checkpoints written before these features existed — the defaults
-        # are exactly the serial-mode values)
+        # checkpoints written before these features existed). Serial
+        # checkpoints of older trees narrowed ``part`` in place and left
+        # ``rpart`` None, which ``_report_part`` reads as "same as part".
         self.rpart = opt_array(sd.get("rpart"), bool)
         self.sampled = bool(sd.get("sampled", False))
         rp = sd.get("reports_pending")
@@ -374,18 +360,10 @@ class RoundScheduler:
             straggler_factor=cfg.straggler_factor))
         # concurrent-cohort mode: client-side phase nodes are keyed
         # (phase, round, cohort) and each cohort pipelines independently;
-        # the engine must expose the per-cohort entry points
-        # (cohort_positions / cohort_local_train / ...)
-        self._concurrent = bool(getattr(cfg, "concurrent_cohorts", False))
-        self._cohort_pos: Optional[List[np.ndarray]] = None
-        if self._concurrent:
-            if not hasattr(engine, "cohort_positions"):
-                raise TypeError(
-                    f"concurrent_cohorts=True needs an engine with the "
-                    f"per-cohort interface (cohort_positions/cohort_*); "
-                    f"{type(engine).__name__} has none")
-            self._cohort_pos = [np.asarray(p, int)
-                                for p in engine.cohort_positions()]
+        # a serial client node covers every cohort in order
+        self._concurrent = cfg.concurrent_cohorts
+        self._cohort_pos = [np.asarray(p, int)
+                            for p in engine.cohort_positions()]
         # node keys in host execution order — (phase, round) for global
         # nodes, (phase, round, cohort) for per-cohort client nodes; the
         # determinism tests pin this, and it is the record of what the
@@ -413,35 +391,23 @@ class RoundScheduler:
         # Byzantine / corruption fault trace: built only when enabled, so
         # the default path never constructs one (bit-for-bit legacy)
         self.faults: Optional[FaultInjector] = None
-        if getattr(cfg, "fault_mode", "none") != "none":
+        if cfg.fault_mode != "none":
             self.faults = FaultInjector(
                 engine.num_clients, mode=cfg.fault_mode, seed=cfg.seed,
-                fault_prob=getattr(cfg, "fault_prob", 0.0),
-                byzantine_frac=getattr(cfg, "byzantine_frac", 0.0),
-                fault_start=getattr(cfg, "fault_start", 0),
-                fault_duration=getattr(cfg, "fault_duration", 0))
+                fault_prob=cfg.fault_prob,
+                byzantine_frac=cfg.byzantine_frac,
+                fault_start=cfg.fault_start,
+                fault_duration=cfg.fault_duration)
         # divergence watchdog: rollback-to-last-healthy-retire on a sick
         # RoundLog. ``_wd_tree`` is the in-memory restore point (a plain
         # nested tree — asdict deep-copies, so later mutation can't alias
         # into it); it is NOT checkpointed and rebuilds at the next
         # healthy retire (or on restore()).
-        self._watchdog = bool(getattr(cfg, "watchdog", False))
+        self._watchdog = cfg.watchdog
         self.rollbacks = 0
         self._wd_best_acc = 0.0
         self._wd_loss_hist: List[float] = []
         self._wd_tree = None
-        # engine entry points resolved once (per-phase interface, with the
-        # historical *_all fallback for pre-built engines)
-        self._local_train = _entry(engine, "phase_local_train",
-                                   "local_train_all")
-        self._report = _entry(engine, "phase_report",
-                              "proxy_logits_and_masks")
-        self._classwise = _entry(engine, "phase_classwise_report",
-                                 "classwise_means_all")
-        self._distill = _entry(engine, "phase_distill", "distill_all")
-        self._distill_private = _entry(engine, "phase_distill_private",
-                                       "distill_private_all")
-        self._eval = _entry(engine, "phase_eval", "evaluate_all")
 
     # ------------------------------------------------------------ the graph
     def _build_deps(self, rounds) -> Dict[Tuple[str, int], List]:
@@ -548,7 +514,7 @@ class RoundScheduler:
         self._done = set()
         self.logs = []
         self.completed = 0
-        if self._watchdog and hasattr(self.engine, "state_dict"):
+        if self._watchdog:
             # arm the rollback point at the window start too — a round-0
             # attack must be as recoverable as a mid-run one
             self._wd_tree = self.snapshot().to_tree()
@@ -626,9 +592,9 @@ class RoundScheduler:
         admission dep of ``local_train(q + max_inflight)``, so entries are
         only dropped once they are ``max_inflight`` rounds stale."""
         del self._states[r]
-        pop_o = getattr(self.server, "pop_round_outlier", None)
-        if pop_o is not None:  # drop the round's suspect scores (the
-            pop_o(r)          # watchdog consumed them on rollback already)
+        # drop the round's suspect scores (the watchdog consumed them on
+        # rollback already)
+        self.server.pop_round_outlier(r)
         self._done -= {k for k in self._done if k[1] == r}
         horizon = r - self.max_inflight
         for key in [k for k in self._sim_end if k[1] <= horizon]:
@@ -653,10 +619,6 @@ class RoundScheduler:
         from repro.fed.state import STATE_VERSION, ExperimentState
         if self._window is None:
             raise RuntimeError("nothing to snapshot — call begin() first")
-        if not hasattr(self.engine, "state_dict"):
-            raise TypeError(
-                f"engine {type(self.engine).__name__} has no state_dict(); "
-                "snapshot/restore needs the per-client state hooks")
         inflight = sorted(
             r for r in self._states
             if any(k[1] == r for k in self._done))
@@ -775,8 +737,7 @@ class RoundScheduler:
         """Top-suspect clients for round ``r`` from the server's normalized
         outlier scores (median ≈ 1 for honest clients): everyone past 3×
         the honest scale, else the single worst scorer."""
-        pop = getattr(self.server, "pop_round_outlier", None)
-        dist = pop(r) if pop is not None else None
+        dist = self.server.pop_round_outlier(r)
         if dist is None or dist.size == 0:
             return []
         bad = np.flatnonzero(~np.isfinite(dist) | (dist > 3.0))
@@ -828,28 +789,31 @@ class RoundScheduler:
         meta = _node_meta(key)
         before = tracing.counts()
         with tracing.span("phase." + phase, **meta) as sp:
-            if len(key) > 2:  # per-cohort client node (concurrent mode)
-                getattr(self, "_phase_" + phase + "_cohort")(st, key[2])
+            body = getattr(self, "_phase_" + phase)
+            if phase in CLIENT_PHASES:
+                # a concurrent node covers its own cohort, a serial node
+                # every cohort in order
+                body(st, key[2:] or range(len(self._cohort_pos)))
             else:
-                getattr(self, "_phase_" + phase)(st)
+                body(st)
         st.phase_s[phase] = st.phase_s.get(phase, 0.0) + sp.s
         self._account(key, st, deps, sp.s)
         if phase == "report":
             # ingestion is an *event* driven by the arrival-trace clock: it
             # runs after the node is priced so each report's simulated
             # arrival time (the client's report-lane finish) is known, and
-            # admission can replay them in arrival order. In concurrent
-            # mode _ingest_reports no-ops until the round's LAST report
-            # node has accumulated and priced its cohort's rows.
+            # admission can replay them in arrival order. _ingest_reports
+            # no-ops until the round's LAST report node has accumulated and
+            # priced its cohorts' rows.
             with tracing.span("server.ingest", **meta) as sp:
                 self._ingest_reports(st)
             st.phase_s[phase] += sp.s
         tracing.book(st.counters, before)
 
     def _report_part(self, st: _RoundState):
-        """The round's *reporting* participants: serial mode mutates
-        ``st.part`` through dropout/admission, concurrent mode keeps the
-        training mask intact and tracks the reduced one in ``st.rpart``."""
+        """The round's *reporting* participants: ``st.rpart``, the training
+        mask ``st.part`` narrowed by dropout and admission (None = same as
+        ``st.part``)."""
         return st.rpart if st.rpart is not None else st.part
 
     def _per_client_cost(self, phase: str, epart) -> Optional[np.ndarray]:
@@ -861,14 +825,8 @@ class RoundScheduler:
         costs = self.sim_phase_costs
         if costs is None or not any("@" in k for k in costs):
             return None
-        cpos = self._cohort_pos
-        if cpos is None:
-            if not hasattr(self.engine, "cohort_positions"):
-                return None
-            cpos = self._cohort_pos = [np.asarray(p, int)
-                                       for p in self.engine.cohort_positions()]
         per = np.zeros((self.engine.num_clients,), float)
-        for ci, pos in enumerate(cpos):
+        for ci, pos in enumerate(self._cohort_pos):
             c = costs.get(f"{phase}@{ci}", costs.get(phase, 0.0))
             n = len(pos) if epart is None else int(epart[pos].sum())
             per[pos] = c / max(n, 1)
@@ -963,89 +921,67 @@ class RoundScheduler:
         # quarantined clients sit the round out like sampled-out ones,
         # draining through the staleness buffer — unless that would empty
         # the round entirely (the protocol needs at least one report)
-        quarantine = getattr(self.server, "quarantine_mask", None)
-        q = quarantine(st.r) if quarantine is not None else None
+        q = self.server.quarantine_mask(st.r)
         if q is not None:
             keep = ~q if st.part is None else (st.part & ~q)
             if keep.any():
                 st.part = keep
-        if st.part is not None:
-            # participants is passed as a kwarg only when a subset was
-            # actually drawn, so pre-existing engines with the historical
-            # interface keep working at participation_fraction=1 (and the
-            # legacy call sequence is preserved bit-for-bit)
-            st.kw = {"participants": st.part}
 
-    def _phase_local_train(self, st: _RoundState) -> None:
-        cfg = self.cfg
-        self._draw_participants(st)
-        st.local_losses = self._local_train(cfg.local_epochs, cfg.batch_size,
-                                            **st.kw)
+    def _fill(self, fleet: List, ci: int, values) -> None:
+        """Scatter cohort ``ci``'s values into a fleet-length list."""
+        for p, v in zip(self._cohort_pos[ci], values):
+            fleet[p] = v
 
-    def _phase_local_train_cohort(self, st: _RoundState, ci: int) -> None:
+    def _phase_local_train(self, st: _RoundState, cohorts) -> None:
         cfg = self.cfg
-        if not st.sampled:  # round-level draw, at the first cohort node
+        if not st.sampled:  # round-level draw, at the round's first node
             self._draw_participants(st)
-        losses = self.engine.cohort_local_train(
-            ci, cfg.local_epochs, cfg.batch_size, participants=st.part)
         if not st.local_losses:
             st.local_losses = [0.0] * self.engine.num_clients
-        for j, p in enumerate(self._cohort_pos[ci]):
-            st.local_losses[p] = losses[j]
+        for ci in cohorts:
+            self._fill(st.local_losses, ci, self.engine.cohort_local_train(
+                ci, cfg.local_epochs, cfg.batch_size, participants=st.part))
 
-    def _phase_report(self, st: _RoundState) -> None:
-        cfg = self.cfg
-        # mid-round dropout: these clients trained (local_train already
-        # priced their lanes) but vanish before reporting — their fresh
-        # report never reaches the server and they sit out the rest of the
-        # round, riding the staleness buffer like any non-participant
-        dropped = dropout_mask(self.engine.num_clients, st.r, seed=cfg.seed,
-                               dropout=cfg.dropout_prob)
-        if dropped is not None:
-            stayed = (~dropped if st.part is None else (st.part & ~dropped))
-            st.part = stayed
-            st.kw = {"participants": st.part}
-        if self.method.data_free:  # FKD/PLS upload class-wise means
-            st.means_counts = self._classwise(**st.kw)
-            return
-        st.idx = self.server.select_indices(cfg.proxy_batch)
-        st.px = self.server.proxy.x[st.idx]
-        st.powner = self.server.proxy.owner[st.idx]
-        # computed here (the client-side work) but ingested post-pricing in
-        # _ingest_reports, once simulated arrival times exist
-        st.report_payload = self._report(st.px, st.powner, **st.kw)
-
-    def _phase_report_cohort(self, st: _RoundState, ci: int) -> None:
+    def _phase_report(self, st: _RoundState, cohorts) -> None:
         cfg = self.cfg
         num = self.engine.num_clients
-        pos = self._cohort_pos[ci]
         if st.reports_pending is None:  # round-level setup, first node
             st.reports_pending = len(self._cohort_pos)
-            # dropout is drawn once per round; the reduced mask lives in
-            # st.rpart so cohorts that have not trained yet still see the
-            # full training mask in st.part
+            # mid-round dropout: these clients trained (local_train already
+            # priced their lanes) but vanish before reporting — their fresh
+            # report never reaches the server and they sit out the rest of
+            # the round, riding the staleness buffer like any
+            # non-participant. Drawn once per round; the reduced mask lives
+            # in st.rpart so cohorts that have not trained yet still see
+            # the full training mask in st.part
             dropped = dropout_mask(num, st.r, seed=cfg.seed,
                                    dropout=cfg.dropout_prob)
             if dropped is not None:
                 st.rpart = (~dropped if st.part is None
                             else (st.part & ~dropped))
-        part = self._report_part(st)
-        if self.method.data_free:
-            mc = self.engine.cohort_classwise_report(ci, participants=part)
-            if st.means_counts is None:
-                k = self.engine.clients[0].num_classes
-                zero = (np.zeros((k, k), np.float32),
-                        np.zeros((k,), np.float32))
-                st.means_counts = [zero] * num
-            for j, p in enumerate(pos):
-                st.means_counts[p] = mc[j]
-        else:
-            if st.idx is None:  # the round's shared proxy batch: one draw,
-                # round-ordered by the cross-round report order deps, so
-                # the server rng stream matches the serial schedule
+            if not self.method.data_free:
+                # the round's shared proxy batch: one draw, round-ordered by
+                # the cross-round report order deps, so the server rng
+                # stream is the same under either graph
                 st.idx = self.server.select_indices(cfg.proxy_batch)
                 st.px = self.server.proxy.x[st.idx]
                 st.powner = self.server.proxy.owner[st.idx]
+        part = self._report_part(st)
+        for ci in cohorts:
+            pos = self._cohort_pos[ci]
+            if self.method.data_free:  # FKD/PLS upload class-wise means
+                mc = self.engine.cohort_classwise_report(ci,
+                                                         participants=part)
+                if st.means_counts is None:
+                    k = self.engine.clients[0].num_classes
+                    zero = (np.zeros((k, k), np.float32),
+                            np.zeros((k,), np.float32))
+                    st.means_counts = [zero] * num
+                self._fill(st.means_counts, ci, mc)
+                continue
+            # computed here (the client-side work) but ingested
+            # post-pricing in _ingest_reports, once simulated arrival times
+            # exist
             lg, mk = self.engine.cohort_report(ci, st.px, st.powner,
                                                participants=part)
             if st.report_logits is None:
@@ -1054,7 +990,7 @@ class RoundScheduler:
                 st.report_masks = np.zeros((num, t), bool)
             st.report_logits[pos] = lg
             st.report_masks[pos] = mk
-        st.reports_pending -= 1
+        st.reports_pending -= len(cohorts)
 
     def _ingest_reports(self, st: _RoundState) -> None:
         """Server-side report ingestion, as an arrival-ordered event.
@@ -1069,21 +1005,15 @@ class RoundScheduler:
         the cap at 0 (default) admission is the identity and the legacy
         lockstep byte stream is preserved bit-for-bit.
 
-        In concurrent-cohort mode the round's rows accumulate across its
-        per-cohort report nodes (``st.report_logits``/``st.report_masks``)
-        and ingestion fires once, at the round's last report node — arrival
-        times were pinned per node at pricing time (``st.report_arrival``),
-        so admission order is independent of how cohorts interleaved."""
-        if self.method.data_free:
-            return
-        if st.report_payload is not None:  # serial: same-node handoff
-            logits, masks = st.report_payload
-            st.report_payload = None
-        elif (st.report_logits is not None and st.reports_pending == 0):
-            logits, masks = st.report_logits, st.report_masks
-            st.report_logits = st.report_masks = None
-        else:  # concurrent: cohorts still reporting
-            return
+        The round's rows accumulate across its report nodes
+        (``st.report_logits``/``st.report_masks``) and ingestion fires
+        once, at the round's last report node — arrival times were pinned
+        per node at pricing time (``st.report_arrival``), so admission
+        order is independent of how cohorts interleaved."""
+        if self.method.data_free or st.reports_pending:
+            return  # nothing to ingest, or cohorts still reporting
+        logits, masks = st.report_logits, st.report_masks
+        st.report_logits = st.report_masks = None
         cfg = self.cfg
         part = self._report_part(st)
         if self.faults is not None:
@@ -1092,8 +1022,7 @@ class RoundScheduler:
             # (seed, round, client), so every engine injects identically.
             logits, masks = self.faults.corrupt_reports(
                 st.r, logits, masks, part)
-        cap = int(getattr(self.server, "max_pending_reports", 0))
-        if cap > 0:
+        if self.server.max_pending_reports > 0:
             ids = (np.arange(self.engine.num_clients)
                    if part is None else np.flatnonzero(part))
             arrival = st.report_arrival[ids]
@@ -1103,12 +1032,7 @@ class RoundScheduler:
             if admitted_ids.size < ids.size:
                 admitted = np.zeros((self.engine.num_clients,), bool)
                 admitted[admitted_ids] = True
-                part = admitted
-                if self._concurrent:
-                    st.rpart = admitted
-                else:
-                    st.part = admitted
-                    st.kw = {"participants": st.part}
+                part = st.rpart = admitted
         # ID fraction over the clients that actually reported; stale rows
         # merged at aggregation additionally carry reuse
         st.id_frac = (float(masks.mean()) if part is None
@@ -1144,44 +1068,31 @@ class RoundScheduler:
         batch against the fused ensemble teacher (the same teacher/validity
         the clients are about to distill from)."""
         cfg = self.cfg
-        epochs = (getattr(cfg, "server_distill_epochs", 0)
-                  or cfg.distill_epochs)
+        epochs = cfg.server_distill_epochs or cfg.distill_epochs
         st.server_distill_loss = self.server.ensemble_distill(
             st.px, st.teacher, st.valid, epochs=epochs,
             batch_size=cfg.batch_size)
 
-    def _phase_distill(self, st: _RoundState) -> None:
-        cfg = self.cfg
-        if self.method.data_free:
-            st.distill_losses = self._distill_private(
-                st.teacher_by_class, st.valid_by_class, cfg.distill_epochs,
-                cfg.batch_size, **st.kw)
-            return
-        w = st.valid.astype(np.float32)
-        st.distill_losses = self._distill(st.px, st.teacher, w,
-                                          cfg.distill_epochs, cfg.batch_size,
-                                          **st.kw)
-
-    def _phase_distill_cohort(self, st: _RoundState, ci: int) -> None:
+    def _phase_distill(self, st: _RoundState, cohorts) -> None:
         cfg = self.cfg
         part = self._report_part(st)
-        if self.method.data_free:
-            losses = self.engine.cohort_distill_private(
-                ci, st.teacher_by_class, st.valid_by_class,
-                cfg.distill_epochs, cfg.batch_size, participants=part)
-        else:
-            w = st.valid.astype(np.float32)
-            losses = self.engine.cohort_distill(
-                ci, st.px, st.teacher, w, cfg.distill_epochs,
-                cfg.batch_size, participants=part)
         if not st.distill_losses:
             st.distill_losses = [0.0] * self.engine.num_clients
-        for j, p in enumerate(self._cohort_pos[ci]):
-            st.distill_losses[p] = losses[j]
+        w = None if self.method.data_free else st.valid.astype(np.float32)
+        for ci in cohorts:
+            if self.method.data_free:
+                losses = self.engine.cohort_distill_private(
+                    ci, st.teacher_by_class, st.valid_by_class,
+                    cfg.distill_epochs, cfg.batch_size, participants=part)
+            else:
+                losses = self.engine.cohort_distill(
+                    ci, st.px, st.teacher, w, cfg.distill_epochs,
+                    cfg.batch_size, participants=part)
+            self._fill(st.distill_losses, ci, losses)
 
     def _phase_eval(self, st: _RoundState) -> None:
-        st.accs = self._eval(self.x_test, self.y_test)
-        if getattr(self.server, "student", None) is not None:
+        st.accs = self.engine.evaluate(self.x_test, self.y_test)
+        if self.server.student is not None:
             st.server_student_acc = self.server.evaluate_student(
                 self.x_test, self.y_test)
 
@@ -1195,10 +1106,8 @@ class RoundScheduler:
         age = max(0.0, st.sim_finish_s - self._last_retire_s)
         self._last_retire_s = max(self._last_retire_s, st.sim_finish_s)
         part = self._report_part(st)
-        pop_s = getattr(self.server, "pop_scrubbed", None)
-        scrubbed = int(pop_s(st.r)) if pop_s is not None else 0
-        pop_q = getattr(self.server, "pop_quarantined", None)
-        newly_q = pop_q(st.r) if pop_q is not None else []
+        scrubbed = int(self.server.pop_scrubbed(st.r))
+        newly_q = self.server.pop_quarantined(st.r)
         return RoundLog(
             round=st.r,
             mean_acc=float(np.mean(st.accs)),

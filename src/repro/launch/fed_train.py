@@ -155,8 +155,7 @@ def add_config_args(ap: argparse.ArgumentParser) -> None:
                          "so a fast cohort's round r+1 training overlaps a "
                          "slow cohort's round r reporting. Identical "
                          "numerics to the serial graph; changes only the "
-                         "simulated timeline. Requires --engine cohort "
-                         "(or any engine exposing cohort_positions)")
+                         "simulated timeline. Works with either engine")
     ap.add_argument("--kernel-backend", default="auto",
                     choices=["auto", "pallas", "jnp"],
                     help="hot-path kernel dispatch (repro.kernels.dispatch): "

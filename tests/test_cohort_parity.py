@@ -233,11 +233,11 @@ def test_evaluate_pads_tail_batch_single_compile():
     # 700 % 512 != 0: the old path compiled (512, 8) AND the (188, 8) tail
     x_test = rng.normal(size=(700, 8)).astype(np.float32)
     y_test = np.asarray(rng.integers(0, 4, size=700))
-    accs = engine.evaluate_all(x_test, y_test)
+    accs = engine.evaluate(x_test, y_test)
     assert len(traces) == 1, (
         f"eval traced {len(traces)} times for one test-set shape "
         f"(shapes: {traces}); the tail batch must be padded, not ragged")
-    engine.evaluate_all(x_test, y_test)
+    engine.evaluate(x_test, y_test)
     assert len(traces) == 1, "second eval of the same shape must hit the cache"
     ref = [c.evaluate(x_test, y_test) for c in clients]
     np.testing.assert_allclose(accs, ref, **TOL)
@@ -277,7 +277,7 @@ def test_transient_engine_adopts_custom_dre_via_loop_fallback():
                          np.full((5, 6), 9.0, np.float32)])     # OOD (d>>thr)
     powner = np.full((10,), -1, np.int32)   # no sample owned by either client
     engine = CohortEngine(clients)
-    _, masks = engine.proxy_logits_and_masks(px, powner)
+    _, masks = engine.cohort_report(0, px, powner)
     ref = np.stack([np.asarray(c.filter_mask(px, powner).mask)
                     for c in clients])
     np.testing.assert_array_equal(masks, ref)
@@ -314,7 +314,7 @@ def test_mixed_dre_cohort_matches_loop():
                          np.full((5, 6), 9.0, np.float32)])     # OOD
     powner = np.full((10,), -1, np.int32)
     engine = CohortEngine(clients)
-    _, masks = engine.proxy_logits_and_masks(px, powner)
+    _, masks = engine.cohort_report(0, px, powner)
     ref = np.stack([np.asarray(c.filter_mask(px, powner).mask)
                     for c in clients])
     np.testing.assert_array_equal(masks, ref)
@@ -333,7 +333,7 @@ def test_transient_engine_unlearned_dre_fails_like_loop():
     px = np.asarray(server.proxy.x[:10])
     powner = np.asarray(server.proxy.owner[:10])
     with pytest.raises(AssertionError, match="learn"):
-        engine.proxy_logits_and_masks(px, powner)
+        engine.cohort_report(0, px, powner)
 
 
 def test_nonuniform_calibration_q_matches_loop():

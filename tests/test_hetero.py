@@ -147,13 +147,36 @@ def test_single_cohort_concurrent_matches_golden_bit_for_bit():
 # ----------------------------------------------------- numerics parity
 
 
-def test_sync_concurrent_is_bitwise_serial_on_mixed_zoo():
+@pytest.mark.parametrize(
+    "part_kw",
+    [
+        dict(),
+        dict(
+            participation_fraction=0.75,
+            staleness_decay=0.5,
+            dropout_prob=0.2,
+            max_pending_reports=3,
+        ),
+    ],
+    ids=["full", "dropout_admission"],
+)
+def test_sync_concurrent_is_bitwise_serial_on_mixed_zoo(part_kw):
     """Sync mode: the concurrent graph reorders nothing the numerics can
     see (order deps pin the host order), so serial and concurrent runs
-    of the same mixed-zoo experiment are bit-identical."""
-    a = simulator.run(_cfg(), "mnist_feat", n_train=600, n_test=200)
-    b = simulator.run(_cfg(concurrent_cohorts=True), "mnist_feat", n_train=600, n_test=200)
+    of the same mixed-zoo experiment are bit-identical — also when
+    sampling and mid-round dropout shrink the round's reporting
+    participants. The admission cap runs too, but admits every report in
+    these two rounds: a cap that cuts follows simulated arrival order,
+    which the two graphs price differently."""
+    a = simulator.run(_cfg(**part_kw), "mnist_feat", n_train=600, n_test=200)
+    b = simulator.run(
+        _cfg(concurrent_cohorts=True, **part_kw),
+        "mnist_feat",
+        n_train=600,
+        n_test=200,
+    )
     assert _rows(a) == _rows(b)
+    assert [r.participants for r in a.rounds] == [r.participants for r in b.rounds]
 
 
 @pytest.mark.parametrize(

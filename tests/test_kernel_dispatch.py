@@ -282,14 +282,14 @@ def test_backend_selection_never_retraces_round_phases():
     px = rng.normal(size=(32, 8)).astype(np.float32)
     teacher = rng.normal(size=(32, 4)).astype(np.float32)
     w = np.ones((32,), np.float32)
-    engine.local_train_all(1, 32)
-    engine.distill_all(px, teacher, w, 1, 32)
+    engine.cohort_local_train(0, 1, 32)
+    engine.cohort_distill(0, px, teacher, w, 1, 32)
     first = len(traces)
     assert first > 0
     for ambient in ("jnp", "pallas", "auto"):
         with dispatch.kernel_backend(ambient):
-            engine.local_train_all(1, 32)
-            engine.distill_all(px, teacher, w, 1, 32)
+            engine.cohort_local_train(0, 1, 32)
+            engine.cohort_distill(0, px, teacher, w, 1, 32)
     assert len(traces) == first, (
         f"flipping the ambient kernel backend retraced a phase: "
         f"{first} -> {len(traces)} traces ({traces})")

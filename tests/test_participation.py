@@ -153,8 +153,9 @@ def test_staleness_decay_one_full_reuse():
 def test_defaults_reproduce_legacy_logs_bit_for_bit(engine):
     """participation_fraction=1.0, staleness_decay=0 (the defaults) must
     leave the round logs *bit-for-bit* identical to the pre-participation
-    protocol — replicated here as the exact legacy call sequence (engine
-    calls without a mask, aggregation without client weights).
+    protocol — replicated here as the exact legacy call sequence (explicit
+    per-cohort engine calls without a mask, scattered back into client
+    order, and aggregation without client weights).
 
     round_mode is pinned to "sync": the legacy sequence IS the lockstep
     order, so the comparison must not follow the REPRO_ROUND_MODE=overlap
@@ -168,18 +169,29 @@ def test_defaults_reproduce_legacy_logs_bit_for_bit(engine):
     method = get_method(cfg.method)
     key = jax.random.PRNGKey(cfg.seed)
     eng.learn_dres(key)
+    cohorts = range(len(eng.cohort_positions()))
+    order = np.argsort(np.concatenate(eng.cohort_positions()))
+
+    def fleet(per_cohort):          # cohort-major rows -> client order
+        return np.concatenate(per_cohort)[order]
+
     for r, log in enumerate(new.rounds):
-        local_losses = eng.local_train_all(cfg.local_epochs, cfg.batch_size)
+        local_losses = fleet([eng.cohort_local_train(ci, cfg.local_epochs,
+                                                     cfg.batch_size)
+                              for ci in cohorts])
         idx = server.select_indices(cfg.proxy_batch)
         px, powner = server.proxy.x[idx], server.proxy.owner[idx]
-        logits, masks = eng.proxy_logits_and_masks(px, powner)
+        reports = [eng.cohort_report(ci, px, powner) for ci in cohorts]
+        logits = fleet([lg for lg, _ in reports])
+        masks = fleet([mk for _, mk in reports])
         teacher, valid = server.aggregate(logits, masks,
                                           sharpen=method.sharpen,
                                           entropy_filter=method.server_filter)
-        distill_losses = eng.distill_all(px, teacher,
-                                         valid.astype(np.float32),
-                                         cfg.distill_epochs, cfg.batch_size)
-        accs = eng.evaluate_all(x_test, y_test)
+        distill_losses = fleet([
+            eng.cohort_distill(ci, px, teacher, valid.astype(np.float32),
+                               cfg.distill_epochs, cfg.batch_size)
+            for ci in cohorts])
+        accs = eng.evaluate(x_test, y_test)
         assert log.accs == accs                               # bit-for-bit
         assert log.mean_acc == float(np.mean(accs))
         assert log.local_loss == float(np.mean(local_losses))
@@ -289,12 +301,13 @@ def test_changing_subset_does_not_retrace_cohort_phases():
     masks = [np.array([True, True, False, False]),
              np.array([False, False, True, True]),
              np.array([True, False, True, False])]
-    engine.local_train_all(1, 32, participants=masks[0])
-    engine.distill_all(px, teacher, w, 1, 32, participants=masks[0])
+    engine.cohort_local_train(0, 1, 32, participants=masks[0])
+    engine.cohort_distill(0, px, teacher, w, 1, 32,
+                          participants=masks[0])
     first = len(traces)
     for m in masks[1:]:
-        engine.local_train_all(1, 32, participants=m)
-        engine.distill_all(px, teacher, w, 1, 32, participants=m)
+        engine.cohort_local_train(0, 1, 32, participants=m)
+        engine.cohort_distill(0, px, teacher, w, 1, 32, participants=m)
     assert len(traces) == first, (
         f"sampling a different subset retraced a phase: "
         f"{first} -> {len(traces)} traces ({traces})")

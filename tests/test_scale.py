@@ -137,12 +137,12 @@ def test_wave_streaming_does_not_retrace():
     px = rng.normal(size=(32, 8)).astype(np.float32)
     teacher = rng.normal(size=(32, 4)).astype(np.float32)
     w = np.ones((32,), np.float32)
-    engine.local_train_all(1, 32)
-    engine.distill_all(px, teacher, w, 1, 32)
+    engine.cohort_local_train(0, 1, 32)
+    engine.cohort_distill(0, px, teacher, w, 1, 32)
     first = len(traces)
     for _ in range(2):
-        engine.local_train_all(1, 32)
-        engine.distill_all(px, teacher, w, 1, 32)
+        engine.cohort_local_train(0, 1, 32)
+        engine.cohort_distill(0, px, teacher, w, 1, 32)
     assert len(traces) == first, (
         f"wave streaming retraced a phase: {first} -> {len(traces)} "
         f"traces ({traces})")
