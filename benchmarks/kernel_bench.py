@@ -112,7 +112,8 @@ def run_wired(quick=False, backends=BACKENDS):
          f"jnp={row['jnp_s']*1e6:.1f}us")
 
     # one distill step: forward + backward through kd_kl_loss (the pallas
-    # path exercises the custom-VJP backward kernel)
+    # path exercises the custom-VJP backward kernel; at K < 128, as here,
+    # both backends take the jnp route, dispatch.kd_kl_route)
     nn, kc = (2048, 10) if quick else (16384, 10)
     s = jax.random.normal(key, (nn, kc)) * 3
     tt = jax.random.normal(jax.random.fold_in(key, 3), (nn, kc)) * 3

@@ -8,8 +8,11 @@ callers mask out proxy samples with no valid teacher (zero ID contributors).
 ``kd_kl_loss`` dispatches its per-sample KL to the fused Pallas kernel
 (``repro.kernels.distill_kl`` — custom-VJP, so it is differentiable
 through both the forward and the fused backward kernel) when the resolved
-``kernel_backend`` is "pallas"; the jnp path below is kept inline and
-op-for-op unchanged (default-backend bit-for-bit guarantee).
+``kernel_backend`` is "pallas" and the class axis K fills at least one
+128-lane tile (K ≥ 128; ``dispatch.kd_kl_route`` holds the rule). At
+K < 128, whatever the backend, and on the jnp backend, it takes the jnp
+path below, kept inline and op-for-op unchanged (default-backend
+bit-for-bit guarantee).
 """
 from __future__ import annotations
 
@@ -31,14 +34,13 @@ def kd_kl_loss(student_logits, teacher_logits, temperature: float = 3.0,
     ambient policy).
     """
     t = temperature
-    if dispatch.resolve(backend) == "pallas":
+    k = student_logits.shape[-1]
+    if dispatch.kd_kl_route(k, backend) == "pallas":
         lead = student_logits.shape[:-1]
-        kl = dispatch.kd_kl_per_sample(
-            student_logits.reshape(-1, student_logits.shape[-1]),
-            teacher_logits.reshape(-1, teacher_logits.shape[-1]),
-            t, backend="pallas").reshape(lead)
+        kl = dispatch.kd_kl_pallas(student_logits.reshape(-1, k),
+                                   teacher_logits.reshape(-1, k),
+                                   t).reshape(lead)
     else:
-        dispatch.count_route("kd_kl", "jnp")
         sp = jax.nn.log_softmax(student_logits.astype(jnp.float32) / t, axis=-1)
         tp = jax.nn.softmax(teacher_logits.astype(jnp.float32) / t, axis=-1)
         tlogp = jax.nn.log_softmax(teacher_logits.astype(jnp.float32) / t, axis=-1)

@@ -177,23 +177,49 @@ def lloyd_step(x, centroids, *, backend: Optional[str] = None):
     return _lloyd_step_jnp(x, centroids)
 
 
+# Narrower than one 128-lane tile, a class axis pads every kernel operand
+# to 128 lanes (and each vmapped call's rows to the kernel's block), so the
+# kernel moves mostly padding; XLA fuses the reference formula over the
+# real (n, K) instead. From one full tile on, the fused kernel keeps its
+# premise: one VMEM pass in place of four HBM intermediates.
+KD_KL_MIN_CLASSES = 128
+
+
+def kd_kl_route(num_classes: int, backend: Optional[str] = None) -> str:
+    """The counted route of one temperature-KL call over ``num_classes``
+    logits: the resolved backend where the class axis fills at least one
+    lane tile (K ≥ 128), else ``"jnp (K<128)"`` whatever backend resolved.
+    The one place the K rule lives; ``core.distill.kd_kl_loss`` asks it."""
+    route = (resolve(backend) if num_classes >= KD_KL_MIN_CLASSES
+             else "jnp (K<128)")
+    return count_route("kd_kl", route)
+
+
+def kd_kl_pallas(student_logits, teacher_logits, temperature: float):
+    """The fused Pallas route, (n, K) -> (n,), uncounted: callers take it
+    only where :func:`kd_kl_route` said ``"pallas"``."""
+    from repro.kernels.distill_kl import ops as kl_ops
+    temp = float(temperature)
+    return shard_local(
+        lambda s, t: kl_ops.kd_kl_per_sample_vjp(s, t, temp),
+        [("batch", None)] * 2, ("batch",))(student_logits, teacher_logits)
+
+
 def kd_kl_per_sample(student_logits, teacher_logits, temperature: float,
                      *, backend: Optional[str] = None):
     """Per-sample temperature-KL (Hinton) distillation loss, (n, K) -> (n,).
 
-    Differentiable on both backends: the Pallas path carries a
-    ``jax.custom_vjp`` whose backward pass is a second fused kernel
-    (softmax recompute + both logit gradients in one VMEM tile).
+    Takes the Pallas kernel only where the resolved backend is "pallas"
+    *and* K ≥ ``KD_KL_MIN_CLASSES`` (128, one lane tile); a narrower class
+    axis always takes the jnp reference, counted as ``"jnp (K<128)"``
+    (:func:`kd_kl_route`). Differentiable on both routes: the Pallas path
+    carries a ``jax.custom_vjp`` whose backward pass is a second fused
+    kernel (softmax recompute + both logit gradients in one VMEM tile).
     ``temperature`` is compile-time static on the Pallas path — gradients
     w.r.t. it are not defined there (they never are in the FD protocol).
     """
-    if count_route("kd_kl", resolve(backend)) == "pallas":
-        from repro.kernels.distill_kl import ops as kl_ops
-        temp = float(temperature)
-        return shard_local(
-            lambda s, t: kl_ops.kd_kl_per_sample_vjp(s, t, temp),
-            [("batch", None)] * 2, ("batch",))(student_logits,
-                                               teacher_logits)
+    if kd_kl_route(student_logits.shape[-1], backend) == "pallas":
+        return kd_kl_pallas(student_logits, teacher_logits, temperature)
     from repro.kernels.distill_kl import ref as kl_ref
     return kl_ref.kd_kl_per_sample(student_logits, teacher_logits,
                                    temperature)

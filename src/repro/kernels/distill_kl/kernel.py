@@ -18,7 +18,9 @@ so a fused distill step never materialises probabilities in HBM in either
 direction.
 
 Grid: 1-D over tiles of n; the class axis K stays whole inside a tile
-(K ≤ a few thousand for FD logits).
+(K ≤ a few thousand for FD logits). Dispatch routes calls here only at
+K ≥ 128 (``repro.kernels.dispatch.kd_kl_route``): a narrower K pads every
+operand to one 128-lane tile, and XLA's fusion of the reference wins.
 """
 from __future__ import annotations
 

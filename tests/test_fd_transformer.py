@@ -92,9 +92,11 @@ def test_transformer_2d_mesh_parity():
 
 
 def test_transformer_2d_mesh_parity_pallas_kernels():
-    """The 2-D parity with the flash-attention and distill-KL kernels on
-    every engine: on the (clients, model) mesh they run under
-    ``shard_map``, attention split over heads on the model axis."""
+    """The 2-D parity with the flash-attention kernel on every engine: on
+    the (clients, model) mesh it runs under ``shard_map``, attention split
+    over heads on the model axis. At this vocabulary (32) distill-KL takes
+    its K < 128 jnp route; ``test_kernel_dispatch`` runs its kernel under a
+    mesh at K = 128."""
     if jax.device_count() >= 4:
         _mesh_parity_prog.check_parity(4, 4, model_shards=2,
                                        dataset="lm_tokens",

@@ -160,19 +160,27 @@ def _kl_inputs(n=300, k=10, seed=0):
     return s, t, w
 
 
+# K = 10 is the FD fleets' class count, which routes to jnp whatever the
+# backend; K = 128 is the narrowest class axis that drives the kernel
+# (interpret mode on CPU) through kd_kl_loss(backend="pallas").
+KL_WIDTHS = [10, 128]
+
+
+@pytest.mark.parametrize("k", KL_WIDTHS)
 @pytest.mark.parametrize("temp", [1.0, 3.0])
-def test_distill_kl_forward_backend_parity(temp):
-    s, t, w = _kl_inputs()
+def test_distill_kl_forward_backend_parity(temp, k):
+    s, t, w = _kl_inputs(k=k)
     l_j = kd_kl_loss(s, t, temp, w, backend="jnp")
     l_p = kd_kl_loss(s, t, temp, w, backend="pallas")
     np.testing.assert_allclose(float(l_j), float(l_p), rtol=1e-5)
 
 
+@pytest.mark.parametrize("k", KL_WIDTHS)
 @pytest.mark.parametrize("wrt", ["student", "teacher"])
-def test_distill_kl_gradient_backend_parity(wrt):
+def test_distill_kl_gradient_backend_parity(wrt, k):
     """No gradient test existed for the kernel before the custom-VJP: the
     fused Pallas backward must match jax.grad through the jnp loss."""
-    s, t, w = _kl_inputs()
+    s, t, w = _kl_inputs(k=k)
 
     def loss(backend):
         if wrt == "student":
@@ -186,18 +194,63 @@ def test_distill_kl_gradient_backend_parity(wrt):
                                rtol=1e-4, atol=1e-6)
 
 
-def test_distill_kl_gradient_parity_under_vmap_jit():
+@pytest.mark.parametrize("k", KL_WIDTHS)
+def test_distill_kl_gradient_parity_under_vmap_jit(k):
     """The cohort engine differentiates the loss inside jit(vmap(...)) —
     the Pallas custom-VJP must batch through the kernel grid."""
     key = jax.random.PRNGKey(5)
-    sb = jax.random.normal(key, (4, 64, 10))
-    tb = jax.random.normal(jax.random.fold_in(key, 1), (4, 64, 10))
+    sb = jax.random.normal(key, (4, 64, k))
+    tb = jax.random.normal(jax.random.fold_in(key, 1), (4, 64, k))
 
     def g(backend):
         return jax.jit(jax.vmap(lambda a, b: jax.grad(
             lambda aa: kd_kl_loss(aa, b, 3.0, backend=backend))(a)))(sb, tb)
 
     np.testing.assert_allclose(np.asarray(g("pallas")), np.asarray(g("jnp")),
+                               rtol=1e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("k, backend, route", [
+    (10, "pallas", "jnp (K<128)"),
+    (127, "pallas", "jnp (K<128)"),
+    (128, "pallas", "pallas"),
+    (10, "jnp", "jnp (K<128)"),
+    (128, "jnp", "jnp"),
+])
+def test_distill_kl_route_follows_class_width(k, backend, route):
+    """The kernel is taken only where K fills a 128-lane tile; both entry
+    points (the loss and the per-sample op) ask the same rule and count
+    the route once a call."""
+    s, t, w = _kl_inputs(n=64, k=k)
+    dispatch.route_counts.clear()
+    loss = kd_kl_loss(s, t, 3.0, w, backend=backend)
+    per = dispatch.kd_kl_per_sample(s, t, 3.0, backend=backend)
+    assert dispatch.route_counts == {("kd_kl", route): 2}
+    np.testing.assert_allclose(float(loss),
+                               float(jnp.sum(per * w) / jnp.sum(w)),
+                               rtol=1e-5)
+
+
+def test_distill_kl_pallas_route_under_mesh():
+    """At K >= 128 the kernel runs under the installed mesh through
+    ``shard_local`` (a Pallas call has no partitioning rule) and matches
+    the jnp loss and gradient."""
+    from jax.sharding import Mesh
+
+    from repro.models.sharding import logical_rules
+
+    s, t, w = _kl_inputs(n=64, k=128)
+    mesh = Mesh(np.array(jax.devices()), ("data",))
+
+    def step(backend):
+        return jax.jit(jax.value_and_grad(
+            lambda a: kd_kl_loss(a, t, 3.0, w, backend=backend)))(s)
+
+    with logical_rules({"batch": "data"}, mesh):
+        l_p, g_p = step("pallas")
+    l_j, g_j = step("jnp")
+    np.testing.assert_allclose(float(l_p), float(l_j), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(g_p), np.asarray(g_j),
                                rtol=1e-4, atol=1e-6)
 
 
@@ -293,6 +346,42 @@ def test_backend_selection_never_retraces_round_phases():
     assert len(traces) == first, (
         f"flipping the ambient kernel backend retraced a phase: "
         f"{first} -> {len(traces)} traces ({traces})")
+
+
+def test_cohort_distill_at_ten_classes_takes_jnp_route_without_retrace():
+    """A cohort built with kernel_backend="pallas" over K = 10 classes
+    distills on the jnp route (the K < 128 rule), and later rounds reuse
+    the compiled phase: the route is counted once a trace, so its count
+    stands still from round to round."""
+    from repro.fed.client import Client
+    from repro.fed.cohort import CohortEngine
+    from repro.models.cnn import MLPClassifier
+    from repro.optim.optimizers import sgd
+
+    k = 10
+    mlp = MLPClassifier(d_in=8, hidden=(16,), num_classes=k)
+    rng = np.random.default_rng(1)
+    opt = sgd(1e-2)
+    key = jax.random.PRNGKey(1)
+    clients = []
+    for cid in range(3):
+        key, sub = jax.random.split(key)
+        clients.append(Client(
+            cid, mlp.apply, mlp.init(sub), opt,
+            rng.normal(size=(64, 8)).astype(np.float32),
+            rng.integers(0, k, size=64), num_classes=k, arch_key="mlp",
+            seed=0, kernel_backend="pallas"))
+    engine = CohortEngine(clients)
+    px = rng.normal(size=(32, 8)).astype(np.float32)
+    teacher = rng.normal(size=(32, k)).astype(np.float32)
+    w = np.ones((32,), np.float32)
+    dispatch.route_counts.clear()
+    engine.cohort_distill(0, px, teacher, w, 1, 32)
+    first = dict(dispatch.route_counts)
+    assert {r for (op, r) in first if op == "kd_kl"} == {"jnp (K<128)"}
+    for _ in range(2):
+        engine.cohort_distill(0, px, teacher, w, 1, 32)
+    assert dict(dispatch.route_counts) == first
 
 
 # ------------------------------------------------------ end-to-end parity
